@@ -84,9 +84,9 @@ def tier_microbench() -> List[Row]:
         inner = tier
         while hasattr(inner, "inner"):
             inner = inner.inner
-        if isinstance(inner, HostTier) and not HostTier._supported():
+        if isinstance(inner, HostTier) and not HostTier.places_host_memory():
             # don't let a no-op masquerade as a transfer in regression CSVs
-            note = "no-op: backend lacks pinned_host (codec only)"
+            note = "no-op: host arrays stay in place here (codec only)"
         rows.append((f"micro.tier_{tier.describe()}_256x1024.us",
                      round(_time(roundtrip, x), 1), note))
     return rows

@@ -55,11 +55,12 @@ def pipeline_bench(quick: bool = False) -> List[Row]:
     from repro.configs.base import MemoryPlan, MeshPlan
     from repro.core.runtime import MemoryRuntime
     from repro.core.tiers import build_stage_tier
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import get_schedule, make_pipelined
     from repro.parallel.sharding import ShardingPlanner
 
     S = len(jax.devices())
-    mesh = jax.make_mesh((S,), ("pod",))
+    mesh = make_mesh((S,), ("pod",))
     W, xb, tgt, stage_fn = _toy(S)
 
     plan = MeshPlan((S,), ("pod",))

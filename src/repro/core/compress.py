@@ -192,29 +192,32 @@ _register_builtin_codecs()
 # ---------------------------------------------------------------------------
 # whole-tensor encode/decode through a codec — the per-page spill path.
 # kernel=True routes through the Pallas twin as ONE block (page-granular
-# scale, bit-identical to the ref per-tensor path by construction).
-def encode_tensor(codec: Codec, x: jax.Array, *, kernel: bool = False,
-                  interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+# scale, bit-identical to the ref per-tensor path by construction); the
+# kernel runs compiled on a TPU and interpreted elsewhere
+# (``kernels.ops._interpret``, the one place that chooses).
+def encode_tensor(codec: Codec, x: jax.Array, *, kernel: bool = False
+                  ) -> Tuple[jax.Array, jax.Array]:
     """Quantize ``x`` (any shape) with one per-tensor scale.
 
     Returns ``(q, scale)`` with ``q.shape == x.shape``.  ``kernel=True``
     uses the codec's Pallas pack twin on the flattened 2D view.
     """
     if kernel and codec.has_kernel:
+        from repro.kernels.ops import _interpret
         x2 = x.reshape(-1, x.shape[-1])
         q2, scales = codec.pack(x2, block_rows=x2.shape[0],
-                                interpret=interpret)
+                                interpret=_interpret())
         return q2.reshape(x.shape), scales[0]
     return codec.compress(x)
 
 
 def decode_tensor(codec: Codec, q: jax.Array, scale: jax.Array,
-                  dtype=jnp.bfloat16, *, kernel: bool = False,
-                  interpret: bool = True) -> jax.Array:
+                  dtype=jnp.bfloat16, *, kernel: bool = False) -> jax.Array:
     if kernel and codec.has_kernel:
+        from repro.kernels.ops import _interpret
         q2 = q.reshape(-1, q.shape[-1])
         x2 = codec.unpack(q2, scale.reshape(1), block_rows=q2.shape[0],
-                          dtype=dtype, interpret=interpret)
+                          dtype=dtype, interpret=_interpret())
         return x2.reshape(q.shape)
     return codec.decompress(q, scale, dtype)
 

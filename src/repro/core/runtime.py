@@ -26,6 +26,7 @@ tier's bandwidth contract — surfaced by ``launch/dryrun.py`` next to XLA's
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
@@ -38,6 +39,8 @@ from repro.core import policy as policy_mod
 from repro.core.dag import LayerDAG, build_dag
 from repro.core.tiers import MemoryTier, TransferHints, build_tier
 from repro.parallel.sharding import ShardingPlanner
+
+log = logging.getLogger(__name__)
 
 # big float aux (e.g. encoder states feeding cross-attention) pool too
 AUX_STASH_NDIM = 3
@@ -74,12 +77,12 @@ class MemoryRuntime:
     def __init__(self, plan: MeshPlan, memory: MemoryPlan,
                  mesh: Optional[Mesh] = None,
                  planner: Optional[ShardingPlanner] = None,
-                 chip: hw.Chip = hw.TPU_V5E,
+                 chip: Optional[hw.Chip] = None,
                  tier: Optional[MemoryTier] = None):
         self.plan = plan
         self.memory = memory
         self.mesh = mesh
-        self.chip = chip
+        self.chip = chip if chip is not None else hw.attached_chip()
         self.planner = planner if planner is not None else ShardingPlanner(plan)
         # ``tier`` overrides the registry resolution — used for runtimes
         # whose tier is built out-of-band (the pipeline stage runtime wraps
@@ -87,6 +90,7 @@ class MemoryRuntime:
         self.tier: MemoryTier = tier if tier is not None \
             else build_tier(memory, self.planner, mesh)
         self._traffic: Dict[str, TierTraffic] = {}
+        self._said_degenerate = False
 
     # ------------------------------------------------------------------
     # traits
@@ -94,6 +98,23 @@ class MemoryRuntime:
     def offloads(self) -> bool:
         """Whether wrapped layers actually move their saved tensors."""
         return self.tier.offloads
+
+    @property
+    def moves_bytes(self) -> bool:
+        """Whether a stash leaves this device's HBM on this runtime's mesh.
+
+        A tier that offloads in principle but is degenerate here (pooled
+        HBM over a pool of one device) says so once in the log."""
+        if not self.offloads:
+            return False
+        if self.tier.leaves_device():
+            return True
+        if not self._said_degenerate:
+            self._said_degenerate = True
+            log.info("memory tier %s has nowhere to stash on mesh %s: "
+                     "layers run unwrapped and nothing leaves HBM",
+                     self.tier.describe(), "x".join(map(str, self.plan.shape)))
+        return False
 
     def describe(self) -> str:
         return (f"runtime[tier={self.tier.describe()} "

@@ -136,6 +136,12 @@ class MemoryTier(abc.ABC):
         """False when stashing is a no-op (tensors stay resident)."""
         return True
 
+    def leaves_device(self) -> bool:
+        """Whether a stash moves bytes out of this device's HBM on this
+        tier's mesh (False for a tier that is degenerate here, such as a
+        pool of one device)."""
+        return self.offloads
+
     def payload_ratio(self) -> float:
         """Stashed bytes per raw byte (codecs shrink this below 1)."""
         return 1.0
@@ -222,6 +228,9 @@ class PooledHbmTier(MemoryTier):
     def pool_devices(self, plan: MeshPlan) -> int:
         return PoolAxes(plan).pool_size(self.memory.placement)
 
+    def leaves_device(self) -> bool:
+        return self.mesh is not None and self.mesh.size > 1
+
     def describe(self) -> str:
         return f"{self.kind}[{self.memory.placement}]"
 
@@ -230,47 +239,35 @@ class PooledHbmTier(MemoryTier):
 class HostTier(MemoryTier):
     """DC-DLA baseline: virtualize against pinned host memory over PCIe.
 
-    Uses ``memory_kind='pinned_host'`` where the backend supports it (TPU
-    does; the CPU test backend silently no-ops and the DC/HC/MC contrast is
-    reproduced in ``sim/``).
+    A stash moves the array to the ``pinned_host`` memory space
+    (``jax.memory.Space.Host``), a fetch moves it back to ``device``;
+    the array keeps its sharding either way.
     """
 
     kind = "host"
 
-    _backend_has_pinned_host: Optional[bool] = None
+    @staticmethod
+    def places_host_memory() -> bool:
+        """Whether the backend keeps host-space arrays off the device.
+
+        TPU and GPU lower a transfer to ``pinned_host`` to a real copy out
+        of HBM, inside jitted programs too.  XLA:CPU has no memory but the
+        host's: its lowering drops memory-space transfers in traced code,
+        so there the tier meters its traffic and the bytes stay where they
+        are (the DC/HC/MC contrast is reproduced in ``sim/``)."""
+        return jax.default_backend() in ("tpu", "gpu")
 
     @classmethod
-    def _supported(cls) -> bool:
-        """True when the backend really exposes a pinned_host memory space.
-
-        The CPU test backend advertises only 'unpinned_host' and its SPMD
-        partitioner rejects the placement annotation under scan — so the
-        host tier must genuinely no-op there (the DC/HC/MC contrast is
-        reproduced in ``sim/`` instead)."""
-        if cls._backend_has_pinned_host is None:
-            try:
-                kinds = {m.kind for m in
-                         jax.devices()[0].addressable_memories()}
-                cls._backend_has_pinned_host = "pinned_host" in kinds
-            except Exception:
-                cls._backend_has_pinned_host = False
-        return cls._backend_has_pinned_host
-
-    @classmethod
-    def _transfer(cls, x: jax.Array, memory_kind: str) -> jax.Array:
-        if not cls._supported():
+    def _transfer(cls, x: jax.Array, space) -> jax.Array:
+        if not cls.places_host_memory():
             return x
-        try:
-            from jax._src.sharding_impls import TransferToMemoryKind  # noqa
-            return jax.device_put(x, TransferToMemoryKind(memory_kind))
-        except Exception:
-            return x
+        return jax.device_put(x, space)
 
     def stash(self, x: jax.Array, hints: TransferHints) -> Payload:
-        return (self._transfer(x, "pinned_host"), None)
+        return (self._transfer(x, jax.memory.Space.Host), None)
 
     def fetch(self, payload: Payload, hints: TransferHints) -> jax.Array:
-        return self._transfer(payload[0], "device")
+        return self._transfer(payload[0], jax.memory.Space.Device)
 
     def bandwidth(self, plan: MeshPlan, chip: hw.Chip = hw.TPU_V5E) -> float:
         """PCIe path, root-complex shared across the host's devices (paper
@@ -344,6 +341,9 @@ class CompressedTier(MemoryTier):
     @property
     def offloads(self) -> bool:
         return self.inner.offloads
+
+    def leaves_device(self) -> bool:
+        return self.inner.leaves_device()
 
     def payload_ratio(self) -> float:
         return self.codec.ratio * self.inner.payload_ratio()
@@ -477,6 +477,9 @@ class SpillTier(MemoryTier):
         else:
             self.overflow.account(accountant, nbytes)
 
+    def leaves_device(self) -> bool:
+        return self.primary.leaves_device() or self.overflow.leaves_device()
+
     def payload_ratio(self) -> float:
         return self.primary.payload_ratio()
 
@@ -542,6 +545,9 @@ class PipelineStageTier(MemoryTier):
     @property
     def offloads(self) -> bool:
         return self.inner.offloads
+
+    def leaves_device(self) -> bool:
+        return self.inner.leaves_device()
 
     def payload_ratio(self) -> float:
         return self.inner.payload_ratio()

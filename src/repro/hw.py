@@ -52,6 +52,24 @@ TPU_V5E = Chip(
     link_bw=50e9,
 )
 
+#: chips by ``jax.Device.device_kind`` (v5e reports "TPU v5 lite")
+CHIPS_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def attached_chip() -> Chip:
+    """The cost-model chip for the backend in use: the attached TPU, looked
+    up by its ``device_kind`` (an unknown kind raises), or :data:`TPU_V5E`
+    as the analytic target on any other backend."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    if dev.device_kind not in CHIPS_BY_KIND:
+        raise ValueError(f"no cost model for TPU kind {dev.device_kind!r}; "
+                         f"known: {sorted(CHIPS_BY_KIND)}")
+    return CHIPS_BY_KIND[dev.device_kind]
+
+
 # Paper Table II device-node: 1024 PEs x 125 MACs x 1 GHz -> 128 TMAC/s
 # = 256 TFLOP/s (1 MAC = 2 FLOPs); 900 GB/s HBM; N=6 links x 25 GB/s.
 PAPER_DEVICE = Chip(

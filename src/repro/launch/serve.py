@@ -1,9 +1,9 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Runs the tier-aware serving stack (serve/engine.py facade over Scheduler /
-KVCacheManager / Session) over pooled KV caches.  On the CPU container use
-``--smoke`` for the reduced twin; on TPU the full config serves against
-the production mesh with the cache striped across the pool.
+KVCacheManager / Session) over pooled KV caches.  The full config serves on
+the devices present (``launch/mesh.mesh_for_devices``); ``--smoke`` swaps in
+the reduced twin.
 
 ``--batch`` / ``--max-len`` may be omitted: the cache manager then sizes
 the decode slots from the serving tier's ``cache_tier_report``.  Cold KV
@@ -58,9 +58,9 @@ import jax
 import numpy as np
 
 from repro.configs import MemoryPlan, RunConfig, TrainConfig, get_arch
-from repro.configs.base import MeshPlan, ShapeConfig
+from repro.configs.base import ShapeConfig
 from repro.core.runtime import MemoryRuntime
-from repro.launch.mesh import make_host_mesh, make_production_mesh, plan_for
+from repro.launch.mesh import enable_compile_cache, mesh_for_devices
 from repro.models.model import build_model
 from repro.serve.disagg import TransferQueue, build_disagg
 from repro.serve.engine import Engine, Request
@@ -195,16 +195,11 @@ def main() -> None:
                  "compose with federation (--peer/--fed-listen)")
     logging.basicConfig(level=logging.INFO)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-        mesh = make_host_mesh()
-        n = len(jax.devices())
-        plan = MeshPlan((2, n // 2), ("data", "model")) if mesh is not None \
-            else MeshPlan((1,), ("data",))
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-        plan = plan_for(multi_pod=args.multi_pod)
+    mesh, plan = mesh_for_devices(multi_pod=args.multi_pod)
 
     shape = ShapeConfig("serve", args.max_len or 128, args.batch or 4,
                         "decode")

@@ -1,8 +1,9 @@
 """Training driver: ``python -m repro.launch.train --arch <id> [...]``.
 
-On real TPU hardware this launches the full config against the production
-mesh; on the CPU container use ``--smoke`` for the reduced same-family twin
-(this is how examples/train_smollm.py trains a ~100M model end-to-end).
+Runs the full config on the devices present (``launch/mesh.mesh_for_devices``:
+the production mesh when the device count matches it, a local mesh
+otherwise, no mesh on one device); ``--smoke`` swaps in the reduced
+same-family twin.
 """
 from __future__ import annotations
 
@@ -11,8 +12,6 @@ import dataclasses
 import logging
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
 
 from repro.configs import (MemoryPlan, PipelinePlan, RunConfig,
                            SHAPES_BY_NAME, TrainConfig, get_arch)
@@ -20,7 +19,8 @@ from repro.configs.base import CheckpointPlan, MeshPlan, ShapeConfig
 from repro.core.dag import build_dag
 from repro.core.policy import plan_memory, summarize
 from repro.data.pipeline import Prefetcher, SyntheticLM
-from repro.launch.mesh import make_host_mesh, make_production_mesh, plan_for
+from repro.launch.mesh import (enable_compile_cache, make_mesh,
+                               mesh_for_devices)
 from repro.models.model import build_model
 from repro.train.chaos import ChaosMonkey, ChaosSchedule
 from repro.train.elastic import ElasticController
@@ -78,27 +78,14 @@ def main() -> None:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
+    mesh, plan = mesh_for_devices(multi_pod=args.multi_pod)
     if args.smoke:
         cfg = cfg.reduced()
-        mesh = make_host_mesh()
-        n = len(jax.devices())
-        plan = MeshPlan((2, n // 2), ("data", "model")) if mesh is not None \
-            else MeshPlan((1,), ("data",))
-        batch = args.batch or max(4, n)
+        batch = args.batch or max(4, len(jax.devices()))
         seq = args.seq or 128
     else:
-        n = len(jax.devices())
-        need = 512 if args.multi_pod else 256
-        if n >= need:
-            mesh = make_production_mesh(multi_pod=args.multi_pod)
-            plan = plan_for(multi_pod=args.multi_pod)
-        else:
-            # full-size model on whatever devices exist (CPU end-to-end
-            # driver: examples/train_smollm.py)
-            mesh = make_host_mesh()
-            plan = MeshPlan((2, n // 2), ("data", "model")) if mesh is not \
-                None else MeshPlan((1,), ("data",))
         sh = SHAPES_BY_NAME[args.shape]
         batch = args.batch or sh.global_batch
         seq = args.seq or sh.seq_len
@@ -118,7 +105,8 @@ def main() -> None:
             raise SystemExit(f"--pipeline-stages {n_stages} needs that many "
                              f"devices (have {len(devs)})")
         if n_stages > 1:
-            pipe_mesh = Mesh(np.array(devs[:n_stages]), ("pod",))
+            pipe_mesh = make_mesh((n_stages,), ("pod",),
+                                  devices=devs[:n_stages])
         mesh = None
         plan = MeshPlan((1,), ("data",))
         pipeline = PipelinePlan(enabled=True,

@@ -61,10 +61,10 @@ class ModelContext:
     def wrap(self, name: str, fn):
         """vDNN-wrap a sub-layer for training (MemoryRuntime.wrap_layer):
         the layer's input feature map is stashed to the configured memory
-        tier, intermediates are recomputed in backward.  No-op for serving /
-        a non-offloading tier / no mesh."""
-        if (self.mode != "train" or self.mesh is None
-                or self.mesh.size <= 1):
+        tier, intermediates are recomputed in backward.  No-op for serving
+        and for a tier whose stash stays in this device's HBM (no
+        offloading, or a pool of one device)."""
+        if self.mode != "train" or not self.runtime.moves_bytes:
             return fn
         return self.runtime.wrap_layer(fn, batch_dim=0, name=name)
 

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models.layers import ModelContext, dense_init
 
@@ -216,7 +214,7 @@ def moe_block(params: dict, ctx: ModelContext, x: jax.Array
         aux = jax.lax.pmean(aux, batch_axes) if batch_axes else aux
         return out.reshape(xb.shape).astype(xb.dtype), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, x_spec),
         out_specs=(x_spec, P()),

@@ -24,8 +24,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro import compat
-from repro.compat import shard_map
 
 from repro.core.compress import INT8_MAX
 
@@ -40,7 +38,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     Call inside shard_map.  x: identical shape on every member; the leading
     dim must be divisible by the axis size.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     assert x.shape[0] % n == 0, (x.shape, n)
@@ -84,7 +82,7 @@ def compressed_all_reduce(x: jax.Array, err: jax.Array, axis_name: str
     new local error).  Convergence: the residual err is added before
     quantization next call (EF-SGD).
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     corrected = x.astype(jnp.float32) + err
     q, scale = _quant(corrected)
     sent = q.astype(jnp.float32) * scale

@@ -40,8 +40,6 @@ from typing import Any, Callable, Dict, Optional, Tuple, Type, Union
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro import compat
-from repro.compat import shard_map
 
 Pytree = Any
 
@@ -123,7 +121,7 @@ class PipelineSchedule(abc.ABC):
         the output), so wall-clock and stash work scale with M + S - 1
         while the analytic contract prices exactly the M real microbatches.
         """
-        S = compat.axis_size(axis_name)
+        S = jax.lax.axis_size(axis_name)
         fn = self.wrap_stage(stage_fn, name=f"{self.name}_stage")
         if S == 1:
             return fn(stage_params, x)
@@ -241,10 +239,10 @@ def make_pipelined(mesh: Mesh, stage_fn: Callable, n_micro: int,
         sp = jax.tree.map(lambda l: l[0], stage_params)  # my stage (size-1)
         return schedule.run(stage_fn, sp, x, n_micro, axis_name)
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(stage_param_spec, P()),
-                     out_specs=P(),
-                     check_vma=False)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(stage_param_spec, P()),
+                         out_specs=P(),
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -664,13 +664,11 @@ class PagedKVCacheManager(KVCacheManager):
         leaves, treedef = jax.tree_util.tree_flatten(page)
         codec_name = self._codec_by_uid.get(uid)
         codec = get_codec(codec_name) if codec_name else None
-        interpret = jax.default_backend() != "tpu"
         items = []
         for x in leaves:
             dtype = x.dtype
             if codec is not None and codec.applies_to(x):
-                q, scale = encode_tensor(codec, x, kernel=self.codec_kernel,
-                                         interpret=interpret)
+                q, scale = encode_tensor(codec, x, kernel=self.codec_kernel)
             else:
                 q, scale = x, None
             payload = self.spill_runtime.stash(
@@ -686,7 +684,6 @@ class PagedKVCacheManager(KVCacheManager):
         so a mid-tree failure leaves the payload intact and the caller
         can re-park the position for a later retry."""
         codec = get_codec(entry.codec) if entry.codec else None
-        interpret = jax.default_backend() != "tpu"
         leaves = []
         for payload, scale, dtype in entry.items:
             q = self.spill_runtime.fetch(
@@ -695,8 +692,7 @@ class PagedKVCacheManager(KVCacheManager):
                 direction="kv_fetch")
             if scale is not None:
                 q = decode_tensor(codec, q, scale, dtype,
-                                  kernel=self.codec_kernel,
-                                  interpret=interpret)
+                                  kernel=self.codec_kernel)
             leaves.append(q)
         for payload, _, _ in entry.items:
             self._discard(payload)

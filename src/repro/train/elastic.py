@@ -32,7 +32,6 @@ import logging
 from typing import Optional, Tuple
 
 import jax
-import numpy as np
 
 log = logging.getLogger(__name__)
 
@@ -62,12 +61,12 @@ class ElasticController:
     def _shrink_pipe_mesh(self, s_new: int, lost_stage: int):
         if s_new <= 1 or self.pipe_mesh is None:
             return None
-        from jax.sharding import Mesh
+        from repro.launch.mesh import make_mesh
         devs = list(self.pipe_mesh.devices.flatten())
         if 0 <= lost_stage < len(devs):
             devs.pop(lost_stage)
         axis = self.run.pipeline.axis_name
-        return Mesh(np.array(devs[:s_new]), (axis,))
+        return make_mesh((s_new,), (axis,), devices=devs[:s_new])
 
     def recover(self, tc, data_iter, lost_stage: int
                 ) -> Tuple[object, object, int]:

@@ -141,8 +141,13 @@ def train(model: Model, tc: TrainConfig, data_iter, *,
             if hasattr(data_iter, "set_state") and "data" in payload:
                 data_iter.set_state(payload["data"])
             log.info("resumed from step %d", start_step)
-        else:
+        elif model.mesh is None:
             state = init_state(model, tc)
+        else:
+            # built in its sharded layout: no device ever holds the whole
+            # state (an eager init lands all of it on the first device)
+            state = jax.jit(lambda: init_state(model, tc),
+                            out_shardings=state_shardings(model, tc))()
 
     times = []
     metrics = {}
@@ -168,9 +173,12 @@ def train(model: Model, tc: TrainConfig, data_iter, *,
                 retries=chaos.retries, backoff=chaos.backoff)
         else:
             state, metrics = step_fn(state, batch)
-        if fault_handler is not None:
-            fault_handler.observe_step(time.perf_counter() - t0)
+        # the step returns once it is enqueued: wait for the device, so the
+        # time is the step's and not the dispatch's
+        jax.block_until_ready(metrics)
         times.append(time.perf_counter() - t0)
+        if fault_handler is not None:
+            fault_handler.observe_step(times[-1])
 
         done = step_idx + 1
         if done % tc.log_every == 0:
@@ -193,7 +201,7 @@ def train(model: Model, tc: TrainConfig, data_iter, *,
             break
     mgr.wait()
     runtime = getattr(model, "runtime", None)
-    if runtime is not None and runtime.offloads:
+    if runtime is not None and runtime.moves_bytes:
         log.info("memory traffic: %s", runtime.traffic_summary())
     stage_runtime = getattr(model, "stage_runtime", None)
     if stage_runtime is not None and stage_runtime.offloads:

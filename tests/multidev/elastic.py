@@ -34,6 +34,7 @@ import tempfile
 import jax
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from repro.configs import (ARCHS, MemoryPlan, MeshPlan, PipelinePlan,
                            RunConfig, TrainConfig)
 from repro.configs.base import CheckpointPlan, ShapeConfig
@@ -46,7 +47,7 @@ from repro.train.loop import make_manager, train
 
 S = len(jax.devices())
 assert S == 2, S
-pipe_mesh = jax.make_mesh((S,), ("pod",))
+pipe_mesh = make_mesh((S,), ("pod",))
 
 CFG = ARCHS["smollm-135m"].reduced(dtype="float32", num_layers=2 * S)
 STEPS = 10

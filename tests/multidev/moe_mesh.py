@@ -2,6 +2,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.configs.base import MeshPlan, ModelConfig, MemoryPlan
 from repro.parallel.sharding import ShardingPlanner
 from repro.models.moe import moe_init, moe_specs, moe_block, _moe_local, use_ep
@@ -42,7 +43,7 @@ np.testing.assert_allclose(np.asarray(out1), np.asarray(ref), rtol=1e-4, atol=1e
 print("local MoE == dense ref OK, aux:", float(aux1))
 
 # 2) mesh path, EP (E=4 % tp=4... use mesh (2,4): E%4==0 -> EP)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 plan = MeshPlan((2, 4), ("data", "model"))
 planner = ShardingPlanner(plan)
 print("use_ep:", use_ep(cfg, planner))

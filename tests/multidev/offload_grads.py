@@ -2,11 +2,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.configs.base import MeshPlan, MemoryPlan
 from repro.parallel.sharding import ShardingPlanner
 from repro.core.offload import maybe_offload
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 plan = MeshPlan((4, 2), ("data", "model"))
 planner = ShardingPlanner(plan)
 

@@ -19,6 +19,7 @@ os.environ.setdefault("XLA_FLAGS",
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.configs.base import MemoryPlan, MeshPlan
 from repro.core.runtime import MemoryRuntime
 from repro.core.tiers import build_stage_tier
@@ -26,7 +27,7 @@ from repro.parallel.pipeline import get_schedule, make_pipelined
 from repro.parallel.sharding import ShardingPlanner
 
 S = len(jax.devices())
-mesh = jax.make_mesh((S,), ("pod",))
+mesh = make_mesh((S,), ("pod",))
 L_per = 2
 key = jax.random.PRNGKey(0)
 W = jax.random.normal(key, (S, L_per, 8, 8)) * 0.3

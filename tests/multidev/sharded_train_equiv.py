@@ -5,6 +5,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.configs import ARCHS, MemoryPlan, MeshPlan, RunConfig, TrainConfig
 from repro.configs.base import ShapeConfig
 from repro.models.model import build_model
@@ -31,7 +32,7 @@ step1 = make_train_step(m1, tc)
 s1b, metrics1 = jax.jit(step1)(s1, batch)
 
 # 8-device mcdla
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 run8 = RunConfig(model=cfg, shape=shape, mesh=MeshPlan((4, 2), ("data", "model")),
                  memory=MemoryPlan(policy="mcdla", placement="bw_aware"), train=tc)
 m8 = build_model(run8, mesh=mesh)
@@ -48,3 +49,20 @@ np.testing.assert_allclose(float(metrics1["loss"]), float(metrics8["loss"]), rto
 for a, b in zip(jax.tree.leaves(s1b["params"]), jax.tree.leaves(s8b["params"])):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-2, atol=2e-4)
 print("sharded mcdla train step == single-device oracle OK")
+
+# the training driver builds the state in its sharded layout, so the jitted
+# step sees the shardings it was compiled for and traces once (the stash
+# meter counts one call per traced layer)
+import tempfile
+from repro.data.pipeline import SyntheticLM
+from repro.train.loop import train
+m8t = build_model(run8, mesh=mesh)
+with tempfile.TemporaryDirectory() as ckpt_dir:
+    tc2 = dataclasses.replace(tc, total_steps=2, checkpoint_every=100,
+                              checkpoint_dir=ckpt_dir)
+    state, _ = train(m8t, tc2, iter(SyntheticLM(cfg, batch=B, seq=S)))
+assert m8t.runtime.traffic_report()["stash"]["calls"] == 1, \
+    m8t.runtime.traffic_report()
+for leaf, want in zip(jax.tree.leaves(state), jax.tree.leaves(sh)):
+    assert leaf.sharding.is_equivalent_to(want, leaf.ndim), (leaf.sharding, want)
+print("sharded train() traces its step once OK")

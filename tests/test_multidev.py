@@ -36,3 +36,4 @@ def test_pipeline_equals_sequential():
 def test_sharded_train_step_equivalence():
     out = run_multidev("sharded_train_equiv.py", timeout=900)
     assert "single-device oracle OK" in out
+    assert "traces its step once OK" in out

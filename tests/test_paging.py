@@ -965,9 +965,8 @@ def test_shared_page_spill_refetch_roundtrip_codecs(model_and_params, codec):
     for want_leaf, got_leaf in zip(jax.tree_util.tree_leaves(filled),
                                    jax.tree_util.tree_leaves(got)):
         if cdc is not None and cdc.applies_to(want_leaf):
-            q, scale = encode_tensor(cdc, want_leaf, interpret=True)
-            want_leaf = decode_tensor(cdc, q, scale, want_leaf.dtype,
-                                      interpret=True)
+            q, scale = encode_tensor(cdc, want_leaf)
+            want_leaf = decode_tensor(cdc, q, scale, want_leaf.dtype)
         np.testing.assert_array_equal(np.asarray(want_leaf),
                                       np.asarray(got_leaf))
 

@@ -501,6 +501,8 @@ def test_two_process_router_drain_over_tcp(tmp_path):
     # 512-host-device XLA_FLAGS into this process's environ; the smoke
     # children must see a clean single-device platform
     env.pop("XLA_FLAGS", None)
+    # the launcher's persistent compile cache stays off in tests
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     args = [sys.executable, "-m", "repro.launch.serve", "--arch",
             "smollm-135m", "--smoke", "--batch", "2", "--max-len", "64",
             "--page-size", "16"]

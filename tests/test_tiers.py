@@ -373,3 +373,83 @@ def test_runtime_resolves_stash_groups():
     auto = MemoryRuntime(SINGLE, MemoryPlan(policy="auto"))
     k = auto.resolve_stash_groups(cfg, shape, cfg.num_layers)
     assert 0 <= k <= cfg.num_layers
+
+
+# ---------------------------------------------------------------------------
+# one device: a host tier still stashes, a pool of one says it cannot
+def _one_device_train_step(policy: str):
+    from repro.configs import RunConfig, TrainConfig, get_arch
+    from repro.configs.base import ShapeConfig
+    from repro.models.model import build_model
+    from repro.train.loop import make_train_step
+    from repro.train.train_state import init_state
+
+    cfg = get_arch("smollm-135m").reduced()
+    B, S = 2, 16
+    tc = TrainConfig(total_steps=1, warmup_steps=0)
+    run = RunConfig(model=cfg, shape=ShapeConfig("t", S, B, "train"),
+                    mesh=MeshPlan((1,), ("data",)),
+                    memory=MemoryPlan(policy=policy), train=tc)
+    model = build_model(run)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": tokens,
+             "positions": jnp.broadcast_to(jnp.arange(S)[None], (B, S))}
+    _, metrics = jax.jit(make_train_step(model, tc))(init_state(model, tc),
+                                                     batch)
+    assert np.isfinite(float(metrics["loss"]))
+    return model.runtime
+
+
+def test_host_policy_stashes_on_one_device():
+    """No mesh, --policy host: every layer's input still leaves through
+    the host tier (metered stash and fetch bytes above 0)."""
+    rep = _one_device_train_step("host").traffic_report()
+    assert rep["stash"]["wire_bytes"] > 0
+    assert rep["fetch"]["wire_bytes"] == rep["stash"]["wire_bytes"]
+
+
+def test_pool_of_one_is_degenerate_and_says_so(caplog):
+    with caplog.at_level("INFO", logger="repro.core.runtime"):
+        runtime = _one_device_train_step("mcdla")
+    assert not runtime.moves_bytes
+    assert runtime.traffic_report()["wire_bytes_total"] == 0.0
+    notes = [r for r in caplog.records if "nowhere to stash" in r.message]
+    assert len(notes) == 1
+
+
+def test_host_memory_placement_is_a_platform_check():
+    """XLA:CPU drops memory-space transfers in traced code; the tier says
+    so through HostTier.places_host_memory, keyed on the backend."""
+    assert HostTier.places_host_memory() == (
+        jax.default_backend() in ("tpu", "gpu"))
+    tier = HostTier(PLANNER, None, MemoryPlan(policy="host"))
+    assert tier.leaves_device()
+    x = jnp.arange(8.0)
+    payload, _ = tier.stash(x, TransferHints())
+    if not HostTier.places_host_memory():
+        assert payload is x
+    np.testing.assert_array_equal(np.asarray(tier.fetch((payload, None),
+                                                        TransferHints())),
+                                  np.asarray(x))
+
+
+def test_cost_model_chip_follows_the_device(monkeypatch):
+    """MemoryRuntime prices tiers on the attached TPU's entry in
+    hw.CHIPS_BY_KIND; an unknown TPU kind raises; off-TPU the analytic
+    target is TPU_V5E."""
+    from types import SimpleNamespace
+
+    from repro import hw
+
+    assert MemoryRuntime(SINGLE, MemoryPlan()).chip is hw.TPU_V5E
+
+    def fake(kind):
+        monkeypatch.setattr(jax, "devices", lambda: [
+            SimpleNamespace(platform="tpu", device_kind=kind)])
+
+    fake("TPU v5 lite")
+    assert MemoryRuntime(SINGLE, MemoryPlan()).chip is hw.TPU_V5E
+    fake("TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        MemoryRuntime(SINGLE, MemoryPlan())

@@ -1,0 +1,106 @@
+"""Ahead-of-time compiles of the serving path's Pallas kernels for a
+described TPU v5e chip, at real widths.
+
+Nothing here runs: the TPU compiler only has to accept each kernel, which
+is what interpret mode cannot show (block-shape tiling rules, scalar
+stores to VMEM, VMEM limits).  The topology is described inside a module
+fixture, never at import time, so that every xdist worker collects the
+same tests and only the worker given this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import flash_attention as fa
+from repro.kernels import offload_pack as op
+from repro.kernels import paged_attention as pa
+
+PAGE = 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip cannot be read back from the
+    # persistent cache without one: keep the cache out of these tests
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _sd(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# smollm-135m (K=3, G=3, hd=64) and h2o-danube-1.8b (K=8, G=4, hd=80)
+@pytest.mark.parametrize("kv_heads,group,hd", [(3, 3, 64), (8, 4, 80)],
+                         ids=["smollm", "danube"])
+@pytest.mark.parametrize("compressed", [False, True],
+                         ids=["raw", "side_pool"])
+def test_paged_decode_compiles(one_chip, kv_heads, group, hd, compressed):
+    B, pp, P, C = 8, 16, 97, 24
+    H = kv_heads * group
+    args = [_sd(one_chip, (B, 1, H, hd), jnp.bfloat16),
+            _sd(one_chip, (P, PAGE, kv_heads, hd), jnp.bfloat16),
+            _sd(one_chip, (P, PAGE, kv_heads, hd), jnp.bfloat16),
+            _sd(one_chip, (B, pp), jnp.int32),
+            _sd(one_chip, (), jnp.int32)]
+    if compressed:
+        args += [_sd(one_chip, (C, PAGE, kv_heads, hd), jnp.int8),
+                 _sd(one_chip, (C, PAGE, kv_heads, hd), jnp.int8),
+                 _sd(one_chip, (C, 1), jnp.float32),
+                 _sd(one_chip, (C, 1), jnp.float32)]
+
+        def fn(q, k, v, pm, ix, kq, vq, ks, vs):
+            return pa.paged_decode_attention(q, k, v, pm, ix, kq_pool=kq,
+                                             vq_pool=vq, k_scale=ks,
+                                             v_scale=vs)
+    else:
+        def fn(q, k, v, pm, ix):
+            return pa.paged_decode_attention(q, k, v, pm, ix)
+    _compile(fn, *args)
+
+
+# one page of smollm K/V as the spill path packs it (rows = page * K,
+# cols = hd, one block), and a multi-block activation shape
+@pytest.mark.parametrize("rows,cols,block_rows",
+                         [(PAGE * 3, 64, PAGE * 3), (1024, 576, 128)],
+                         ids=["one_page", "multi_block"])
+@pytest.mark.parametrize("codec", ["int8", "fp8", "blocksparse"])
+def test_pack_unpack_compiles(one_chip, codec, rows, cols, block_rows):
+    pack = {"int8": op.int8_pack, "fp8": op.fp8_pack,
+            "blocksparse": op.blocksparse_pack}[codec]
+    unpack = {"int8": op.int8_unpack, "fp8": op.fp8_unpack,
+              "blocksparse": op.blocksparse_unpack}[codec]
+    qdtype = jnp.float8_e4m3fn if codec == "fp8" else jnp.int8
+    nb = rows // block_rows
+    _compile(lambda x: pack(x, block_rows=block_rows),
+             _sd(one_chip, (rows, cols), jnp.bfloat16))
+    _compile(lambda q, s: unpack(q, s, block_rows=block_rows),
+             _sd(one_chip, (rows, cols), qdtype),
+             _sd(one_chip, (nb,), jnp.float32))
+
+
+def test_flash_attention_fwd_compiles(one_chip):
+    # smollm-135m: 9 query heads over 3 kv heads, hd 64, a 1k context
+    B, H, K, S, hd = 2, 9, 3, 1024, 64
+    _compile(lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True),
+             _sd(one_chip, (B, H, S, hd), jnp.bfloat16),
+             _sd(one_chip, (B, K, S, hd), jnp.bfloat16),
+             _sd(one_chip, (B, K, S, hd), jnp.bfloat16))

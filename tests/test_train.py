@@ -146,3 +146,39 @@ def test_weight_decay_skips_scalars_and_clip():
     assert float(metrics["grad_norm"]) == pytest.approx(400.0)
     assert bool(jnp.all(jnp.isfinite(new_p["w"])))
     assert float(new_p["scale"]) == pytest.approx(1.0)   # zero grad, no decay
+
+
+def test_launch_mesh_rule_and_auto_axes():
+    """Both launchers share one mesh rule: no mesh on one device; every
+    mesh the repo builds has Auto axes (the sharding constraints and the
+    FSDP embedding gather rely on GSPMD propagation)."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh, mesh_for_devices
+
+    if len(jax.devices()) == 1:
+        assert mesh_for_devices() == (None, PLAN1)
+    mesh = make_mesh((1,), ("data",))
+    assert mesh.axis_types == (AxisType.Auto,)
+    pinned = make_mesh((1,), ("pod",), devices=jax.devices()[:1])
+    assert pinned.axis_types == (AxisType.Auto,)
+
+
+def test_step_time_waits_for_the_device(monkeypatch, tmp_path):
+    """The step time the loop logs (and the straggler monitor sees) ends
+    in block_until_ready on the step's outputs, not at the enqueue."""
+    waited = []
+    block = jax.block_until_ready
+
+    def spy(x):
+        waited.append(sorted(x))
+        return block(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    tc = TrainConfig(total_steps=2, warmup_steps=1, log_every=1,
+                     checkpoint_every=100,
+                     checkpoint_dir=str(tmp_path))
+    run = RunConfig(model=CFG, shape=ShapeConfig("t", 16, 2, "train"),
+                    mesh=PLAN1, memory=MemoryPlan(policy="none"), train=tc)
+    train(build_model(run), tc, iter(SyntheticLM(CFG, 2, 16)))
+    assert len(waited) == 2 and all("loss" in keys for keys in waited)
