@@ -136,7 +136,7 @@ def phase_train(cfg, seed: int) -> None:
     print(f"train: {ARCH} full width, batch {batch} x seq {seq}, {steps} "
           f"steps; none losses {_fmt(base_loss)}; host losses "
           f"{_fmt(host_loss)}; max relative gap {gap:.3g}; host stash "
-          f"{stash:.0f} B per traced layer, payload memory_kind {kind}; "
+          f"{stash:.0f} B in {steps} steps, payload memory_kind {kind}; "
           f"smoke timing (not a metric): {base_t}; {host_t}")
 
 
@@ -309,7 +309,7 @@ def phase_pool(cfg, seed: int) -> None:
     print(f"pool: {ARCH} full width on a 2x2 (data, model) mesh, batch "
           f"{batch} x seq {seq}, {steps} steps; none losses "
           f"{_fmt(no_loss)}; mcdla losses {_fmt(mc_loss)}; max relative gap "
-          f"{gap:.3g}; mcdla stash {stash:.0f} B per traced layer, payload "
+          f"{gap:.3g}; mcdla stash {stash:.0f} B in {steps} steps, payload "
           f"on {spread} devices at 1/{payload.size // shard} each; "
           f"gather/scatter/permute ops in the step: {collectives}; step "
           f"temporaries per device (compiled): {temps}; peak_bytes_in_use "

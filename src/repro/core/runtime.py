@@ -18,13 +18,17 @@ the stash collective of layer *i* with the compute of layer *i+1* — the TPU
 analogue of the paper's DMA/compute overlap.  Cheap intermediates are
 recomputed in backward (footnote 4) because the vjp re-runs the layer body.
 
-Every stash/fetch is metered at trace time: :meth:`traffic_report` gives
-per-tier logical and wire bytes plus an estimated transfer time against the
-tier's bandwidth contract — surfaced by ``launch/dryrun.py`` next to XLA's
+Every stash/fetch is metered in bytes per direction.  Inside the train
+step that ``train/loop.jit_train_step`` builds, what tracing the step meters
+is one step's traffic (``per_step``, a scanned layer counted once per trip
+through :meth:`repeat`), added to the totals each time the step runs;
+eager callers add as they run.  :meth:`traffic_report` gives both, and
+``launch/dryrun.py`` surfaces the per-step figure next to XLA's
 ``memory_analysis()`` numbers.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 from typing import Any, Callable, Dict, Optional, Sequence
@@ -49,19 +53,15 @@ AUX_STASH_NDIM = 3
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class TierTraffic:
-    """Trace-time transfer meter for one direction through the tier.
-
-    Counts are per *traced* call: a layer wrapped inside ``jax.lax.scan``
-    traces its body once, so multiply by the trip count (the dry-run's
-    group count) for whole-step totals.
-    """
+    """Transfer meter for one direction through the tier: calls and bytes
+    moved (executed totals, or one step's record in ``per_step``)."""
 
     calls: int = 0
     raw_bytes: float = 0.0        # tensor bytes before compression
     wire_bytes: float = 0.0       # bytes that actually cross the interconnect
 
-    def add(self, raw: float, wire: float) -> None:
-        self.calls += 1
+    def add(self, raw: float, wire: float, calls: int = 1) -> None:
+        self.calls += calls
         self.raw_bytes += raw
         self.wire_bytes += wire
 
@@ -89,7 +89,11 @@ class MemoryRuntime:
         # the configured backing store in a PipelineStageTier).
         self.tier: MemoryTier = tier if tier is not None \
             else build_tier(memory, self.planner, mesh)
-        self._traffic: Dict[str, TierTraffic] = {}
+        self._traffic: Dict[str, TierTraffic] = {}     # executed totals
+        self._per_step: Dict[str, TierTraffic] = {}    # one step, as traced
+        self._step_book: Optional[Dict[str, TierTraffic]] = None
+        self._times = 1           # executions one traced transfer stands for
+        self.step_traces = 0
         self._said_degenerate = False
 
     # ------------------------------------------------------------------
@@ -148,11 +152,20 @@ class MemoryRuntime:
 
     # ------------------------------------------------------------------
     # accounting
+    def _add(self, direction: str, raw: float, wire: float,
+             calls: int = 1) -> None:
+        """Meter one transfer: into the step being traced, if any (see
+        :meth:`recording_step`), else into the executed totals."""
+        book = self._traffic if self._step_book is None else self._step_book
+        n = self._times
+        book.setdefault(direction, TierTraffic()).add(raw * n, wire * n,
+                                                      calls * n)
+
     def _meter(self, direction: str, x: jax.Array,
                hints: Optional[TransferHints] = None) -> None:
         raw = float(x.size) * jnp.dtype(x.dtype).itemsize
-        wire = raw * self.tier.wire_ratio(x, hints or TransferHints())
-        self._traffic.setdefault(direction, TierTraffic()).add(raw, wire)
+        self._add(direction, raw,
+                  raw * self.tier.wire_ratio(x, hints or TransferHints()))
 
     def meter_transfer(self, direction: str, raw_bytes: float,
                        wire_bytes: float, calls: int = 1) -> None:
@@ -163,41 +176,68 @@ class MemoryRuntime:
         serve/transport.py, metered as ``kv_wire`` with the exact frame
         byte count — record themselves here so ``traffic_report()`` stays
         the single reconciliation point for every byte that moved."""
-        t = self._traffic.setdefault(direction, TierTraffic())
-        t.calls += calls
-        t.raw_bytes += raw_bytes
-        t.wire_bytes += wire_bytes
+        self._add(direction, raw_bytes, wire_bytes, calls)
+
+    @contextlib.contextmanager
+    def _times_as(self, n: int):
+        prev, self._times = self._times, n
+        try:
+            yield
+        finally:
+            self._times = prev
+
+    def repeat(self, n: int):
+        """Context: each transfer traced inside stands for ``n`` executed
+        ones — a layer body under ``jax.lax.scan`` traces once and runs
+        once per trip.  Nested repeats multiply."""
+        return self._times_as(self._times * n)
+
+    @contextlib.contextmanager
+    def recording_step(self):
+        """Context for the body of a jitted step, which runs only when the
+        step is traced: what is metered inside becomes the step's
+        ``per_step`` record (replacing the last trace's, never adding to
+        it), counted by :meth:`count_step` each time the step runs."""
+        outer, self._step_book = self._step_book, {}
+        try:
+            yield
+            self._per_step = self._step_book
+            self.step_traces += 1
+        finally:
+            self._step_book = outer
+
+    def count_step(self) -> None:
+        """One executed step: add its recorded traffic to the totals."""
+        for direction, t in self._per_step.items():
+            self._traffic.setdefault(direction, TierTraffic()).add(
+                t.raw_bytes, t.wire_bytes, t.calls)
 
     def reset_traffic(self) -> None:
         self._traffic = {}
 
     def traffic_report(self) -> Dict[str, Any]:
-        """Per-tier byte/stall accounting of every metered stash/fetch."""
-        bw = self.tier.bandwidth(self.plan, self.chip)
-        n_dev = max(self.plan.num_devices, 1)
-        report: Dict[str, Any] = {
-            "tier": self.tier.describe(),
-            "bandwidth_per_dev": bw,
-        }
+        """Per-direction executed calls and bytes of every metered transfer;
+        ``per_step``: the wire bytes one run of the traced step moves."""
+        report: Dict[str, Any] = {"tier": self.tier.describe(),
+                                  "step_traces": self.step_traces}
         total_wire = 0.0
-        for direction, t in sorted(self._traffic.items()):
+        for direction in sorted(set(self._traffic) | set(self._per_step)):
+            t = self._traffic.get(direction, TierTraffic())
             report[direction] = {
                 "calls": t.calls, "raw_bytes": t.raw_bytes,
                 "wire_bytes": t.wire_bytes,
+                "per_step": self._per_step.get(
+                    direction, TierTraffic()).wire_bytes,
             }
             total_wire += t.wire_bytes
         report["wire_bytes_total"] = total_wire
-        # global bytes stream through n_dev links in parallel
-        report["est_transfer_s"] = (total_wire / (bw * n_dev)
-                                    if bw > 0 and total_wire else 0.0)
         return report
 
     def traffic_summary(self) -> str:
         r = self.traffic_report()
         per = {d: f"{fmt_bytes(v['wire_bytes'])}/{v['calls']}x"
                for d, v in r.items() if isinstance(v, dict)}
-        return (f"tier={r['tier']} wire={fmt_bytes(r['wire_bytes_total'])} "
-                f"est_transfer={r['est_transfer_s']*1e3:.2f}ms {per}")
+        return f"tier={r['tier']} wire={fmt_bytes(r['wire_bytes_total'])} {per}"
 
     # ------------------------------------------------------------------
     # data path (metered tier passthrough).  ``direction`` labels the
@@ -235,8 +275,7 @@ class MemoryRuntime:
         hints = hints or TransferHints()
         payload = self.tier.stash(x, hints)
         raw = float(x.size) * jnp.dtype(x.dtype).itemsize
-        self._traffic.setdefault(direction, TierTraffic()).add(
-            raw, self._payload_bytes(payload))
+        self._add(direction, raw, self._payload_bytes(payload))
         return payload
 
     def restore_snapshot(self, payload,
@@ -247,7 +286,7 @@ class MemoryRuntime:
         wire = self._payload_bytes(payload)
         x = self.tier.fetch(payload, hints)
         raw = float(x.size) * jnp.dtype(x.dtype).itemsize
-        self._traffic.setdefault(direction, TierTraffic()).add(raw, wire)
+        self._add(direction, raw, wire)
         return x
 
     def discard(self, payload) -> None:
@@ -293,11 +332,16 @@ class MemoryRuntime:
                                  batch_dim=batch_dim, dtype=dtype,
                                  allow_compress=allow_compress, name=name)
 
+        # the backward is traced after the forward's scan has returned: it
+        # meters its fetches with the trip count the forward saw
+        trips = [1]
+
         @jax.custom_vjp
         def f(params, x, *aux):
             return layer_fn(params, x, *aux)
 
         def f_fwd(params, x, *aux):
+            trips[0] = runtime._times
             y = layer_fn(params, x, *aux)
             payload = runtime.stash(x, hints_for())
             witness = jnp.zeros((), x.dtype)    # dtype token (residuals must
@@ -314,22 +358,25 @@ class MemoryRuntime:
 
         def f_bwd(res, gy):
             params, payload, witness, saved_aux = res
-            x = runtime.fetch(payload, hints_for(dtype=witness.dtype))
-            aux = []
-            for sa in saved_aux:
-                if isinstance(sa, tuple):
-                    # aux tensors differ in rank/shape from the residual —
-                    # they derive their own fetch layout (never the static
-                    # residual compute_spec).  The payload's first array
-                    # leaf carries the stashed shape (tier payloads may
-                    # wrap it, e.g. SpillTier's leg-routing node).
-                    shape = jax.tree_util.tree_leaves(sa)[0].shape
-                    aux.append(runtime.fetch(sa, TransferHints(
-                        compute_spec=runtime._aux_spec(compute_spec, shape),
-                        batch_dim=batch_dim, dtype=witness.dtype,
-                        allow_compress=False, name=f"{name}_aux")))
-                else:
-                    aux.append(sa)
+            with runtime._times_as(trips[0]):
+                x = runtime.fetch(payload, hints_for(dtype=witness.dtype))
+                aux = []
+                for sa in saved_aux:
+                    if isinstance(sa, tuple):
+                        # aux tensors differ in rank/shape from the
+                        # residual — they derive their own fetch layout
+                        # (never the static residual compute_spec).  The
+                        # payload's first array leaf carries the stashed
+                        # shape (tier payloads may wrap it, e.g.
+                        # SpillTier's leg-routing node).
+                        shape = jax.tree_util.tree_leaves(sa)[0].shape
+                        aux.append(runtime.fetch(sa, TransferHints(
+                            compute_spec=runtime._aux_spec(compute_spec,
+                                                           shape),
+                            batch_dim=batch_dim, dtype=witness.dtype,
+                            allow_compress=False, name=f"{name}_aux")))
+                    else:
+                        aux.append(sa)
             aux = tuple(aux)
             flags = _split_aux(aux)
             diff_aux = tuple(a for a, fl in zip(aux, flags) if fl)
@@ -340,7 +387,8 @@ class MemoryRuntime:
                              for a, fl in zip(aux, flags))
                 return layer_fn(p, xx, *full)
 
-            _, vjp = jax.vjp(call, params, x, *diff_aux)
+            with jax.named_scope("tier.recompute"):
+                _, vjp = jax.vjp(call, params, x, *diff_aux)
             grads = vjp(gy)
             dp, dx, d_diff = grads[0], grads[1], list(grads[2:])
             if compute_spec is not None:
@@ -400,7 +448,8 @@ class MemoryRuntime:
                     direction="act_fetch")
                 if isinstance(leaf, StashedLeaf) else leaf,
                 saved, is_leaf=lambda l: isinstance(l, StashedLeaf))
-            _, vjp = jax.vjp(stage_fn, params, tree)
+            with jax.named_scope("tier.recompute"):
+                _, vjp = jax.vjp(stage_fn, params, tree)
             return vjp(gy)
 
         f.defvjp(f_fwd, f_bwd)

@@ -26,8 +26,8 @@ from repro.configs import (ARCHS, MemoryPlan, RunConfig, SHAPES_BY_NAME,  # noqa
 from repro.configs.registry import cells_for  # noqa: E402
 from repro.launch.mesh import make_production_mesh, plan_for  # noqa: E402
 from repro.models.model import build_model  # noqa: E402
-from repro.train.loop import make_train_step  # noqa: E402
-from repro.train.train_state import abstract_state, state_shardings  # noqa: E402
+from repro.train.loop import jit_train_step  # noqa: E402
+from repro.train.train_state import abstract_state  # noqa: E402
 
 _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                 "u8": 1, "pred": 1, "f8e4m3fn": 1, "s64": 8, "u64": 8,
@@ -202,7 +202,6 @@ def _lower_one(cfg, shape_name: str, *, multi_pod: bool, policy: str,
     run = RunConfig(model=cfg, shape=shape, mesh=plan, memory=memory,
                     train=tc)
     model = build_model(run, mesh=mesh)
-    model.runtime.reset_traffic()
     t0 = time.time()
 
     batch_sds = model.input_specs(shape)
@@ -211,13 +210,8 @@ def _lower_one(cfg, shape_name: str, *, multi_pod: bool, policy: str,
 
     with mesh:
         if shape.mode == "train":
-            step = make_train_step(model, tc)
-            state_sds = abstract_state(model, tc)
-            state_sh = state_shardings(model, tc)
-            lowered = jax.jit(
-                step, in_shardings=(state_sh, batch_sh),
-                out_shardings=(state_sh, None),
-                donate_argnums=0).lower(state_sds, batch_sds)
+            lowered = jit_train_step(model, tc, batch_sh).lower(
+                abstract_state(model, tc), batch_sds)
         elif shape.mode == "prefill":
             params_sds = model.abstract_params()
             params_sh = model.param_shardings()
@@ -256,9 +250,8 @@ def _lower_one(cfg, shape_name: str, *, multi_pod: bool, policy: str,
         ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
-    # per-tier stash/fetch traffic metered while tracing the step (counts
-    # are per traced layer group; scan bodies trace once — see
-    # MemoryRuntime.traffic_report)
+    # the tier traffic one run of the step moves (``per_step``: recorded
+    # when the step was traced, scanned layers counted once per trip)
     traffic = model.runtime.traffic_report()
     res = {
         "shape": shape_name,
@@ -277,6 +270,12 @@ def _lower_one(cfg, shape_name: str, *, multi_pod: bool, policy: str,
         "traffic": traffic,
     }
     return res
+
+
+def per_step_bytes(traffic: Dict) -> float:
+    """Wire bytes one run of the train step moves through the tier."""
+    return sum(v.get("per_step", 0.0) for v in traffic.values()
+               if isinstance(v, dict))
 
 
 # ---------------------------------------------------------------------------
@@ -434,7 +433,7 @@ def main() -> int:
                       f"flops/dev={r['flops_per_dev']:.3e} "
                       f"coll/dev={r['collective_wire_bytes_per_dev']/1e9:.3f}GB "
                       f"tier[{tr.get('tier', '?')}]="
-                      f"{tr.get('wire_bytes_total', 0.0)/1e9:.3f}GB/group")
+                      f"{per_step_bytes(tr)/1e9:.3f}GB/step")
                 if "pipeline" in r:
                     p = r["pipeline"]
                     print(f"       pipeline[{p['schedule']} "

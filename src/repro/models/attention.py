@@ -342,8 +342,10 @@ def attention_block(params: dict, ctx: ModelContext, x: jax.Array,
             o = blockwise_attention(q, k, v, causal=causal, window=window,
                                     softcap=cfg.logit_softcap)
     else:
-        o = blockwise_attention(q, k, v, causal=causal, window=window,
-                                softcap=cfg.logit_softcap)
+        # scores, softmax and values: the part a flash kernel replaces
+        with jax.named_scope("attention_core"):
+            o = blockwise_attention(q, k, v, causal=causal, window=window,
+                                    softcap=cfg.logit_softcap)
 
     if tp > 1 and H % tp != 0 and S > 1:
         o = ctx.act(o, "batch", "seq", None, None)

@@ -97,16 +97,18 @@ class Model:
                 frames=batch.get("frames"), patches=batch.get("patches"),
                 stash_groups=self.stash_groups)
         table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-        # hoist the FSDP (data-axis) gather of the table out of the chunk
-        # scan: vocab stays model-sharded, D gathered ONCE (§Perf: was
-        # re-gathered per chunk, 8x the wire)
-        table = ctx.act(table, "tensor", None)
-        h = ctx.act(h, "batch", None, None)   # gather S once for the CE scan
-        labels = batch["labels"]
-        mask = (labels >= 0).astype(jnp.float32)
-        loss, n_tok = chunked_cross_entropy(
-            h, table, jnp.maximum(labels, 0), mask,
-            constrain_logits=lambda lg: ctx.act(lg, "batch", None, "tensor"))
+        with jax.named_scope("loss"):
+            # hoist the FSDP (data-axis) gather of the table out of the
+            # chunk scan: vocab stays model-sharded, D gathered ONCE
+            # (§Perf: was re-gathered per chunk, 8x the wire)
+            table = ctx.act(table, "tensor", None)
+            h = ctx.act(h, "batch", None, None)   # gather S once for the CE
+            labels = batch["labels"]
+            mask = (labels >= 0).astype(jnp.float32)
+            loss, n_tok = chunked_cross_entropy(
+                h, table, jnp.maximum(labels, 0), mask,
+                constrain_logits=lambda lg: ctx.act(lg, "batch", None,
+                                                    "tensor"))
         total = loss + AUX_WEIGHT * aux
         return total, {"loss": loss, "aux_loss": aux, "tokens": n_tok}
 

@@ -87,6 +87,7 @@ def mlp_specs(cfg: ModelConfig, planner) -> dict:
     return s
 
 
+@jax.named_scope("mlp")
 def mlp_block(params: dict, ctx: ModelContext, x: jax.Array) -> jax.Array:
     act = activation_fn(ctx.cfg.act)
     h = jnp.einsum("bsd,df->bsf", x, params["w1"])
@@ -368,14 +369,18 @@ def forward_train(params: Params, ctx: ModelContext, tokens: jax.Array,
         stash_groups = n_groups
     g1 = max(0, min(n_groups, stash_groups))
     aux = jnp.zeros((), jnp.float32)
-    if g1 > 0:
-        p1 = jax.tree.map(lambda l: l[:g1], stacked)
-        (x, aux), _ = jax.lax.scan(make_body(True), (x, aux), p1,
-                                   unroll=_unroll())
-    if g1 < n_groups:
-        p2 = jax.tree.map(lambda l: l[g1:], stacked)
-        (x, aux), _ = jax.lax.scan(make_body(False), (x, aux), p2,
-                                   unroll=_unroll())
+    # "layers" also names the host tier's stash and fetch: XLA folds them
+    # into the scan's residual stacking, out of any scope inside the body
+    with jax.named_scope("layers"):
+        if g1 > 0:
+            p1 = jax.tree.map(lambda l: l[:g1], stacked)
+            with ctx.runtime.repeat(g1):
+                (x, aux), _ = jax.lax.scan(make_body(True), (x, aux), p1,
+                                           unroll=_unroll())
+        if g1 < n_groups:
+            p2 = jax.tree.map(lambda l: l[g1:], stacked)
+            (x, aux), _ = jax.lax.scan(make_body(False), (x, aux), p2,
+                                       unroll=_unroll())
     x = apply_norm(cfg, params["final_norm"], x)
     return x, aux
 

@@ -2,6 +2,8 @@
 accumulation, checkpointing, fault handling, and metrics."""
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -48,12 +50,16 @@ def make_train_step(model: Model, tc: TrainConfig
             (l, metrics), g = jax.value_and_grad(loss, has_aux=True)(
                 params, batch)
             return g, l, metrics
-        return accumulate_microbatches(loss, params, batch, tc.grad_accum)
+        # microbatches are scanned: each layer's transfers run once a trip
+        with model.runtime.repeat(tc.grad_accum):
+            return accumulate_microbatches(loss, params, batch,
+                                           tc.grad_accum)
 
     def train_step(state, batch):
         g, l, metrics = grads_of(state["params"], batch)
-        params, opt, opt_metrics = apply_adamw(state["params"], g,
-                                               state["opt"], tc)
+        with jax.named_scope("optimizer"):
+            params, opt, opt_metrics = apply_adamw(state["params"], g,
+                                                   state["opt"], tc)
         new_state = dict(state, params=params, opt=opt,
                          step=state["step"] + 1)
         metrics = dict(metrics, **opt_metrics)
@@ -62,15 +68,48 @@ def make_train_step(model: Model, tc: TrainConfig
     return train_step
 
 
-def jit_train_step(model: Model, tc: TrainConfig, batch_shardings=None):
+class TrainStep:
+    """The jitted train step, counting the memory tiers' traffic of each
+    run: tracing the step records one step's transfers in each runtime
+    (``per_step``), and every call adds that record to the runtime's
+    executed totals.  ``lower`` traces without running, so it records
+    the per-step figure and counts nothing."""
+
+    def __init__(self, jitted, runtimes):
+        self.jitted = jitted
+        self.runtimes = runtimes
+
+    def __call__(self, state, batch):
+        out = self.jitted(state, batch)
+        for r in self.runtimes:
+            r.count_step()
+        return out
+
+    def lower(self, *args, **kwargs):
+        return self.jitted.lower(*args, **kwargs)
+
+
+def jit_train_step(model: Model, tc: TrainConfig, batch_shardings=None
+                   ) -> TrainStep:
     step = make_train_step(model, tc)
+    runtimes = [r for r in (model.runtime, model.stage_runtime)
+                if r is not None]
+
+    @functools.wraps(step)
+    def recorded(state, batch):
+        # runs only while jax traces the step
+        with contextlib.ExitStack() as stack:
+            for r in runtimes:
+                stack.enter_context(r.recording_step())
+            return step(state, batch)
+
     if model.mesh is None:
-        return jax.jit(step, donate_argnums=0)
+        return TrainStep(jax.jit(recorded, donate_argnums=0), runtimes)
     shardings = state_shardings(model, tc)
-    return jax.jit(step,
-                   in_shardings=(shardings, batch_shardings),
-                   out_shardings=(shardings, None),
-                   donate_argnums=0)
+    return TrainStep(jax.jit(recorded,
+                             in_shardings=(shardings, batch_shardings),
+                             out_shardings=(shardings, None),
+                             donate_argnums=0), runtimes)
 
 
 # ---------------------------------------------------------------------------

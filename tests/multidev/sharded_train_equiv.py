@@ -51,8 +51,8 @@ for a, b in zip(jax.tree.leaves(s1b["params"]), jax.tree.leaves(s8b["params"])):
 print("sharded mcdla train step == single-device oracle OK")
 
 # the training driver builds the state in its sharded layout, so the jitted
-# step sees the shardings it was compiled for and traces once (the stash
-# meter counts one call per traced layer)
+# step sees the shardings it was compiled for and traces once (the runtime
+# records one step's transfers per trace and counts them per run)
 import tempfile
 from repro.data.pipeline import SyntheticLM
 from repro.train.loop import train
@@ -61,8 +61,9 @@ with tempfile.TemporaryDirectory() as ckpt_dir:
     tc2 = dataclasses.replace(tc, total_steps=2, checkpoint_every=100,
                               checkpoint_dir=ckpt_dir)
     state, _ = train(m8t, tc2, iter(SyntheticLM(cfg, batch=B, seq=S)))
-assert m8t.runtime.traffic_report()["stash"]["calls"] == 1, \
-    m8t.runtime.traffic_report()
+rep = m8t.runtime.traffic_report()
+assert rep["step_traces"] == 1, rep
+assert rep["stash"]["calls"] == tc2.total_steps * cfg.num_layers, rep
 for leaf, want in zip(jax.tree.leaves(state), jax.tree.leaves(sh)):
     assert leaf.sharding.is_equivalent_to(want, leaf.ndim), (leaf.sharding, want)
 print("sharded train() traces its step once OK")

@@ -348,11 +348,22 @@ def test_runtime_traffic_report():
     rep = runtime.traffic_report()
     raw = float(x.size) * x.dtype.itemsize
     assert rep["tier"] == "pooled_hbm[bw_aware]"
+    # eager: counted as it ran, and no step was recorded
     assert rep["stash"]["raw_bytes"] == pytest.approx(raw)
     assert rep["fetch"]["raw_bytes"] == pytest.approx(raw)
-    assert rep["est_transfer_s"] > 0
+    assert rep["stash"]["per_step"] == 0.0
     runtime.reset_traffic()
     assert runtime.traffic_report()["wire_bytes_total"] == 0.0
+    # recorded as one step's traffic: counted only when the step runs
+    with runtime.recording_step():
+        jax.grad(lambda p, xx: jnp.sum(wrapped(p, xx, pos) ** 2))(params, x)
+    rep = runtime.traffic_report()
+    assert rep["stash"]["per_step"] == rep["fetch"]["per_step"] == \
+        pytest.approx(raw)
+    assert rep["wire_bytes_total"] == 0.0
+    runtime.count_step()
+    assert runtime.traffic_report()["wire_bytes_total"] == \
+        pytest.approx(2 * raw)
     assert "tier=" in runtime.traffic_summary()
 
 

@@ -1,13 +1,15 @@
-"""Ahead-of-time compiles of the serving path's Pallas kernels for a
-described TPU v5e chip, at real widths.
+"""Ahead-of-time compiles for a described TPU v5e chip: the serving path's
+Pallas kernels at real widths, and a small host-tier train step.
 
 Nothing here runs: the TPU compiler only has to accept each kernel, which
 is what interpret mode cannot show (block-shape tiling rules, scalar
-stores to VMEM, VMEM limits).  The topology is described inside a module
+stores to VMEM, VMEM limits), and the step's compiled text shows where
+the named scopes land.  The topology is described inside a module
 fixture, never at import time, so that every xdist worker collects the
 same tests and only the worker given this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,3 +106,45 @@ def test_flash_attention_fwd_compiles(one_chip):
              _sd(one_chip, (B, H, S, hd), jnp.bfloat16),
              _sd(one_chip, (B, K, S, hd), jnp.bfloat16),
              _sd(one_chip, (B, K, S, hd), jnp.bfloat16))
+
+
+def test_host_tier_train_step_scopes(one_chip, monkeypatch):
+    """The host tier's transfers come out of the layer scan's residual
+    stacking, so they carry the ``layers`` scope (none inside the body);
+    the recompute keeps its own scope through fusion."""
+    from repro.configs import ARCHS, MemoryPlan, MeshPlan, RunConfig, \
+        TrainConfig
+    from repro.configs.base import ShapeConfig
+    from repro.core.tiers import HostTier
+    from repro.models.model import build_model
+    from repro.train.loop import jit_train_step
+    from repro.train.train_state import init_state
+
+    # the tier decides by the default backend, which is the CPU here
+    monkeypatch.setattr(HostTier, "places_host_memory",
+                        staticmethod(lambda: True))
+    B, S = 2, 16
+    tc = TrainConfig(total_steps=4, warmup_steps=0)
+    model = build_model(RunConfig(
+        model=ARCHS["smollm-135m"].reduced(),
+        shape=ShapeConfig("t", S, B, "train"),
+        mesh=MeshPlan((1,), ("data",)), memory=MemoryPlan(policy="host"),
+        train=tc))
+    state = jax.tree.map(lambda x: _sd(one_chip, x.shape, x.dtype),
+                         jax.eval_shape(lambda: init_state(model, tc)))
+    batch = {k: _sd(one_chip, (B, S), jnp.int32)
+             for k in ("tokens", "labels", "positions")}
+    text = jit_train_step(model, tc).lower(state, batch).compile().as_text()
+    named = {}
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if "S(5)" in line and " = " in line and m:
+            name = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+            named[name] = m.group(1)
+    # tuples and their elements carry no op_name and take no device time
+    starts = [n for n in named if n.startswith(("dynamic-slice-start",
+                                                "dynamic-update-slice-"))]
+    assert starts, named
+    assert all("layers" in re.split(r"[/()]", op) for op in named.values()), \
+        named
+    assert "/tier.recompute/" in text

@@ -1,5 +1,8 @@
-"""Training substrate: convergence, resume, 8-bit Adam, grad accumulation."""
+"""Training substrate: convergence, resume, 8-bit Adam, grad accumulation,
+the step's named scopes and the tier's per-step byte counter."""
+import collections
 import dataclasses
+import re
 import tempfile
 
 import jax
@@ -12,7 +15,7 @@ from repro.configs.base import ShapeConfig
 from repro.data.pipeline import SyntheticLM
 from repro.models.model import build_model
 from repro.train.fault import FaultHandler
-from repro.train.loop import make_train_step, train
+from repro.train.loop import jit_train_step, make_train_step, train
 from repro.train.optimizer import (apply_adamw, init_opt_state, lr_schedule,
                                    opt_state_specs)
 from repro.train.train_state import init_state
@@ -182,3 +185,117 @@ def test_step_time_waits_for_the_device(monkeypatch, tmp_path):
                     mesh=PLAN1, memory=MemoryPlan(policy="none"), train=tc)
     train(build_model(run), tc, iter(SyntheticLM(CFG, 2, 16)))
     assert len(waited) == 2 and all("loss" in keys for keys in waited)
+
+
+# ---------------------------------------------------------------------------
+# the step's named scopes and the memory tier's per-step byte counter
+SCOPES = ("attention_core", "mlp", "tier.recompute", "loss", "optimizer",
+          "layers")
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _host_step(B=2, S=16, grad_accum=1):
+    tc = TrainConfig(total_steps=4, warmup_steps=0, grad_accum=grad_accum)
+    run = RunConfig(model=CFG, shape=ShapeConfig("t", S, B, "train"),
+                    mesh=PLAN1, memory=MemoryPlan(policy="host"), train=tc)
+    model = build_model(run)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
+                                CFG.vocab_size)
+    batch = {"tokens": tokens, "labels": tokens,
+             "positions": jnp.broadcast_to(jnp.arange(S)[None], (B, S))}
+    return model, tc, jit_train_step(model, tc), batch
+
+
+def _scopes(op_name):
+    """Scope names on an op_name path, transforms (``jvp(...)``) taken
+    off."""
+    return [re.sub(r"^(?:\w+\()+|\)+$", "", seg)
+            for seg in op_name.split("/")]
+
+
+def _pre_fusion_ops(hlo, root="jit(train_step)"):
+    """(opcode, op_name) of every instruction of a lowered module.  XLA
+    keeps a called computation's op_names relative to its caller: each is
+    made whole by putting the caller's path in front."""
+    instr = re.compile(r"^\s+(?:ROOT )?%?[\w.-]+ = .*? ([a-z][\w-]*)\(")
+    callee = re.compile(r"(?:to_apply|body|condition|calls)=%?([\w.-]+)")
+    comps, caller, comp = {}, {}, None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[line.startswith("ENTRY")].lstrip("%")
+            comps[comp] = []
+            continue
+        m = instr.match(line)
+        if m is None or comp is None:
+            continue
+        op = OP_NAME.search(line)
+        name = op.group(1) if op else ""
+        comps[comp].append((m.group(1), name))
+        for c in callee.findall(line):
+            caller[c] = (comp, name)
+
+    def whole(c, name):
+        if name.startswith(root) or c not in caller:
+            return name
+        prefix = whole(*caller[c])
+        return f"{prefix}/{name}" if name else prefix
+
+    return [(opc, whole(c, n)) for c in comps for opc, n in comps[c]]
+
+
+@pytest.fixture(scope="module")
+def host_step_hlo():
+    model, tc, step, batch = _host_step()
+    lowered = step.lower(jax.eval_shape(lambda: init_state(model, tc)),
+                         batch)
+    return (lowered.as_text(dialect="hlo", debug_info=True),
+            lowered.compile().as_text())
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_step_ops_carry_their_scope(host_step_hlo, scope):
+    pre, post = host_step_hlo
+    assert any(scope in _scopes(n) for n in OP_NAME.findall(post))
+    if scope != "tier.recompute":
+        return
+    ops = _pre_fusion_ops(pre)
+    under = [n for _, n in ops if "tier.recompute" in _scopes(n)]
+    # the re-run forward only: the backward's own ops stay outside it
+    assert under and not any(
+        "transpose(" in n.split("tier.recompute", 1)[1] for n in under)
+
+    def einsums(names):
+        return collections.Counter(_scopes(n)[-2] for n in names)
+
+    dots = [n for opc, n in ops if opc == "dot"]
+    recompute = einsums(n for n in dots if "tier.recompute" in _scopes(n))
+    forward = einsums(n for n in dots if "jvp(layers)" in n.split("/")
+                      and "tier.recompute" not in _scopes(n))
+    # one forward of the layer body, less the MLP's down projection: its
+    # output is the layer's, which the backward does not need
+    assert recompute == forward - collections.Counter({"bsf,fd->bsd": 1})
+
+
+@pytest.mark.parametrize("runs,grad_accum", [(0, 1), (1, 1), (3, 1),
+                                             (1, 2)])
+def test_tier_counter_counts_executed_steps(runs, grad_accum):
+    B, S = 2, 16
+    model, tc, step, batch = _host_step(B, S, grad_accum)
+    rt = model.runtime
+    state = init_state(model, tc)
+    step.lower(state, batch)               # traced, not run
+    jax.clear_caches()
+    step.lower(state, batch)               # a second trace replaces the
+    assert rt.step_traces == 2             # record, never adds to it
+    per = CFG.num_layers * B * S * CFG.d_model * jnp.dtype(CFG.dtype).itemsize
+    rep = rt.traffic_report()
+    for d in ("stash", "fetch"):
+        assert rep[d]["per_step"] == per
+        assert rep[d]["calls"] == 0 and rep[d]["wire_bytes"] == 0
+    for _ in range(runs):
+        state, _ = step(state, batch)
+    rep = rt.traffic_report()
+    for d in ("stash", "fetch"):
+        assert rep[d]["per_step"] == per
+        assert rep[d]["wire_bytes"] == rep[d]["raw_bytes"] == runs * per
+        assert rep[d]["calls"] == runs * CFG.num_layers * grad_accum
