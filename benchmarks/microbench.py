@@ -35,9 +35,9 @@ def kernel_microbench() -> List[Row]:
     rows.append(("micro.gemm_xla_ref.us",
                  round(_time(jax.jit(ref.gemm_ref), x, w), 1), ""))
 
-    q = jax.random.normal(key, (1, 4, 256, 64))
-    k = jax.random.normal(key, (1, 2, 256, 64))
-    v = jax.random.normal(key, (1, 2, 256, 64))
+    q = jax.random.normal(key, (1, 256, 4, 64))
+    k = jax.random.normal(key, (1, 256, 2, 64))
+    v = jax.random.normal(key, (1, 256, 2, 64))
     rows.append(("micro.flash_fwd_256.us",
                  round(_time(lambda *a: ops.flash_attention(*a, True, 0),
                              q, k, v), 1), "interpret mode"))

@@ -2,13 +2,14 @@
 
 Each op auto-selects interpret mode off-TPU (the kernels are written for
 TPU BlockSpec/VMEM semantics; interpret=True executes the same kernel body
-on CPU for correctness).  ``flash_attention`` adds the custom_vjp pairing:
-Pallas forward + XLA-blockwise backward recompute.
+on CPU for correctness).  ``flash_attention`` pairs the Pallas forward
+with the Pallas backward in one custom_vjp; ``train_attention`` picks it or
+the XLA blockwise twin from what the call can observe.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -89,31 +90,65 @@ def paged_attention(q, k_pool, v_pool, page_map, cache_index, *,
 
 
 # ---------------------------------------------------------------------------
+# training attention: the flash kernel pair where it can run, else the XLA
+# blockwise twin.  The counter records, at trace time, which path each call
+# took (a scanned layer stack traces its body once per trace).
+_ATTENTION_CALLS = {"pallas": 0, "xla": 0}
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def attention_paths() -> Dict[str, int]:
+    """Traced training-attention calls so far, by path."""
+    return dict(_ATTENTION_CALLS)
+
+
+def train_attention(q, k, v, *, causal: bool, window: int = 0,
+                    softcap: float = 0.0, meshed: bool = False) -> jax.Array:
+    """q: (B, S, H, hd); k/v: (B, T, K, hd) -> (B, S, H, hd).
+
+    The Pallas kernel pair on a TPU without a multi-device mesh (a Pallas
+    call has no GSPMD partitioning rule) and without a logit soft-cap;
+    ``models/attention.blockwise_attention`` otherwise."""
+    path = "pallas" if _on_tpu() and not meshed and softcap == 0 else "xla"
+    _ATTENTION_CALLS[path] += 1
+    if path == "pallas":
+        return flash_attention(q, k, v, causal, window)
+    from repro.models.attention import blockwise_attention
+    return blockwise_attention(q, k, v, causal=causal, window=window,
+                               softcap=softcap)
+
+
+def _heads_major(x):
+    return x.swapaxes(1, 2)                 # (B, S, H, d) <-> (B, H, S, d)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0):
-    """q: (B, H, S, d); k/v: (B, Hkv, T, d).  Pallas forward; backward
-    recomputes through the XLA blockwise twin (exact same math)."""
-    return fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                  interpret=_interpret())
-
-
-def _fa_ref(q, k, v, causal, window):
-    # XLA blockwise twin, in (B, S, H, d) layout
-    from repro.models.attention import blockwise_attention
-    o = blockwise_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
-                            v.swapaxes(1, 2), causal=causal, window=window)
-    return o.swapaxes(1, 2)
+    """q: (B, S, H, hd); k/v: (B, T, K, hd) with H = K * G.  Pallas
+    forward and Pallas backward (kernels/flash_attention.py); the only
+    residuals are q, k, v, the output and its per-row logsumexp."""
+    o = fa.flash_attention_fwd(_heads_major(q), _heads_major(k),
+                               _heads_major(v), causal=causal, window=window,
+                               interpret=_interpret())
+    return _heads_major(o)
 
 
 def _fa_fwd(q, k, v, causal, window):
-    return flash_attention(q, k, v, causal, window), (q, k, v)
+    qh, kh, vh = _heads_major(q), _heads_major(k), _heads_major(v)
+    o, lse = fa.flash_attention_fwd(qh, kh, vh, causal=causal, window=window,
+                                    save_lse=True, interpret=_interpret())
+    return _heads_major(o), (qh, kh, vh, o, lse)
 
 
 def _fa_bwd(causal, window, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(lambda q, k, v: _fa_ref(q, k, v, causal, window),
-                     q, k, v)
-    return vjp(g)
+    qh, kh, vh, o, lse = res
+    grads = fa.flash_attention_bwd(qh, kh, vh, o, lse, _heads_major(g),
+                                   causal=causal, window=window,
+                                   interpret=_interpret())
+    return tuple(_heads_major(x) for x in grads)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

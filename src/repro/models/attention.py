@@ -1,13 +1,18 @@
 """Attention: GQA/MHA/SWA with a blockwise (flash-style) XLA implementation.
 
-Two execution paths:
+Three execution paths:
 
-* ``blockwise_attention`` — training / prefill.  Unrolled python loop over
-  query chunks gives each chunk a *static* causal / sliding-window KV span
-  (no wasted FLOPs on fully-masked blocks), and an inner ``lax.scan`` with an
-  online softmax keeps the score tensor at (chunk × chunk) instead of S×S.
-  This is the pure-XLA twin of kernels/flash_attention.py (the Pallas TPU
-  kernel) — both are validated against kernels/ref.py.
+* training (``attention_block`` without a cache) goes through
+  ``kernels/ops.train_attention``: the Pallas flash kernels, forward and
+  backward, on one TPU chip; ``blockwise_attention`` on other backends,
+  under a multi-device mesh, or with a logit soft-cap.
+
+* ``blockwise_attention`` — the XLA twin of those kernels, and the prefill
+  path.  Unrolled python loop over query chunks gives each chunk a *static*
+  causal / sliding-window KV span (no wasted FLOPs on fully-masked blocks),
+  and an inner ``lax.scan`` with an online softmax keeps the score tensor
+  at (chunk × chunk) instead of S×S.  Both are validated against
+  kernels/ref.py.
 
 * ``decode_attention`` — single-token decode against a KV cache.  The cache
   is sharded over the sequence axis (the paper's pooled memory applied to
@@ -342,10 +347,14 @@ def attention_block(params: dict, ctx: ModelContext, x: jax.Array,
             o = blockwise_attention(q, k, v, causal=causal, window=window,
                                     softcap=cfg.logit_softcap)
     else:
-        # scores, softmax and values: the part a flash kernel replaces
+        # scores, softmax and values: the flash kernel pair on one TPU chip,
+        # the blockwise twin elsewhere (kernels/ops.train_attention)
+        from repro.kernels import ops as kops
+        meshed = ctx.mesh is not None and ctx.mesh.size > 1
         with jax.named_scope("attention_core"):
-            o = blockwise_attention(q, k, v, causal=causal, window=window,
-                                    softcap=cfg.logit_softcap)
+            o = kops.train_attention(q, k, v, causal=causal, window=window,
+                                     softcap=cfg.logit_softcap,
+                                     meshed=meshed)
 
     if tp > 1 and H % tp != 0 and S > 1:
         o = ctx.act(o, "batch", "seq", None, None)

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (flash_attention_bwd,
+                                           flash_attention_fwd)
 from repro.kernels.gemm_os import gemm_os, pick_blocks
 from repro.kernels.offload_pack import fp8_pack, fp8_unpack
 from repro.kernels.ssd_scan import ssd_scan
@@ -63,23 +64,54 @@ def test_flash_attention_sweep(B, H, Hkv, S, T, d, causal, window,
                                rtol=tol, atol=tol * 5)
 
 
-def test_flash_attention_grads_match_ref():
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 4, 128, 32))
-    k = jax.random.normal(ks[1], (1, 2, 128, 32))
-    v = jax.random.normal(ks[2], (1, 2, 128, 32))
+# largest gradient error over the largest reference gradient: f32 reads
+# about 1e-6 (summation order); bf16 about 7e-3, the rounding of p and dS
+# as MXU operands and of the bf16 gradients (2**-8 = 3.9e-3) themselves
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("B,K,G,S,hd,causal,window", [
+    (2, 3, 3, 640, 64, True, 0),        # smollm groups and head dim
+    (1, 2, 4, 1100, 80, True, 0),       # danube's; two padded tiles
+    (1, 2, 4, 1024, 80, True, 200),     # sliding window
+    (2, 2, 1, 200, 128, False, 0),      # MHA, non-causal, padded keys
+], ids=["gqa3_hd64_causal", "gqa4_hd80_causal", "gqa4_hd80_window",
+        "mha_hd128_full"])
+def test_flash_attention_grads_match_ref(B, K, G, S, hd, causal, window,
+                                         dtype, tol):
+    """dQ, dK, dV of the kernel pair against the dense reference under
+    ``jax.grad``: through ``ops.flash_attention`` (model layout, tiles from
+    the shapes), and through the kernels at 128-row tiles, where blocks
+    above the diagonal or below the window are skipped."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, S, K * G, hd)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, S, K, hd)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, S, K, hd)).astype(dtype)
+    w = jax.random.normal(ks[3], (B, S, K * G, hd))
 
     def loss(q, k, v):
-        return jnp.sum(ops.flash_attention(q, k, v, True, 0) ** 2)
+        o = ops.flash_attention(q, k, v, causal, window)
+        return jnp.sum(o.astype(jnp.float32) * w)
 
     def loss_ref(q, k, v):
-        return jnp.sum(ref.attention_ref(q, k, v, causal=True) ** 2)
+        o = ref.attention_ref(*(x.astype(jnp.float32).swapaxes(1, 2)
+                                for x in (q, k, v)),
+                              causal=causal, window=window)
+        return jnp.sum(o.swapaxes(1, 2) * w)
 
-    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    heads = [x.swapaxes(1, 2) for x in (q, k, v)]
+    kw = dict(causal=causal, window=window, bq=128, bk=128, interpret=True)
+    o, lse = flash_attention_fwd(*heads, save_lse=True, **kw)
+    small = flash_attention_bwd(*heads, o, lse,
+                                w.swapaxes(1, 2).astype(dtype), **kw)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-3)
+    for g in (jax.grad(loss, argnums=(0, 1, 2))(q, k, v),
+              [x.swapaxes(1, 2) for x in small]):
+        for name, a, b in zip("qkv", g, gr):
+            assert a.dtype == dtype and a.shape == b.shape
+            b = np.asarray(b, np.float32)
+            err = np.max(np.abs(np.asarray(a, np.float32) - b)) \
+                / np.abs(b).max()
+            assert err < tol, (f"d{name}", err)
 
 
 # ---------------------------------------------------------------------------

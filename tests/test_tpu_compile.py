@@ -1,5 +1,6 @@
-"""Ahead-of-time compiles for a described TPU v5e chip: the serving path's
-Pallas kernels at real widths, and a small host-tier train step.
+"""Ahead-of-time compiles for a described TPU v5e chip: the Pallas kernels
+of the serving and training paths at real widths, and a small host-tier
+train step.
 
 Nothing here runs: the TPU compiler only has to accept each kernel, which
 is what interpret mode cannot show (block-shape tiling rules, scalar
@@ -99,30 +100,60 @@ def test_pack_unpack_compiles(one_chip, codec, rows, cols, block_rows):
              _sd(one_chip, (nb,), jnp.float32))
 
 
-def test_flash_attention_fwd_compiles(one_chip):
-    # smollm-135m: 9 query heads over 3 kv heads, hd 64, a 1k context
-    B, H, K, S, hd = 2, 9, 3, 1024, 64
-    _compile(lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True),
-             _sd(one_chip, (B, H, S, hd), jnp.bfloat16),
-             _sd(one_chip, (B, K, S, hd), jnp.bfloat16),
-             _sd(one_chip, (B, K, S, hd), jnp.bfloat16))
+# smollm-135m (9 query heads over 3 kv heads, hd 64) and h2o-danube-1.8b
+# (32 over 8, hd 80) at a 2k context: the training attention's shapes
+FLASH_SHAPES = pytest.mark.parametrize("kv_heads,group,hd",
+                                       [(3, 3, 64), (8, 4, 80)],
+                                       ids=["smollm", "danube"])
+
+
+def _flash_args(sharding, kv_heads, group, hd, B=2, S=2048):
+    H = kv_heads * group
+    q = _sd(sharding, (B, H, S, hd), jnp.bfloat16)
+    kv = _sd(sharding, (B, kv_heads, S, hd), jnp.bfloat16)
+    lse = _sd(sharding, (B, H, 1, S), jnp.float32)
+    return q, kv, lse
+
+
+@FLASH_SHAPES
+def test_flash_attention_fwd_compiles(one_chip, kv_heads, group, hd):
+    q, kv, _ = _flash_args(one_chip, kv_heads, group, hd)
+    _compile(lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True,
+                                                    save_lse=True),
+             q, kv, kv)
+
+
+@FLASH_SHAPES
+def test_flash_attention_bwd_compiles(one_chip, kv_heads, group, hd):
+    q, kv, lse = _flash_args(one_chip, kv_heads, group, hd)
+    compiled = _compile(
+        lambda q, k, v, o, l, do: fa.flash_attention_bwd(q, k, v, o, l, do,
+                                                         causal=True),
+        q, kv, kv, q, lse, q)
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call") \
+        == 2                                    # the dK/dV and the dQ kernel
 
 
 def test_host_tier_train_step_scopes(one_chip, monkeypatch):
     """The host tier's transfers come out of the layer scan's residual
     stacking, so they carry the ``layers`` scope (none inside the body);
-    the recompute keeps its own scope through fusion."""
+    the recompute keeps its own scope through fusion; the attention's
+    Pallas kernels carry ``attention_core``."""
     from repro.configs import ARCHS, MemoryPlan, MeshPlan, RunConfig, \
         TrainConfig
     from repro.configs.base import ShapeConfig
     from repro.core.tiers import HostTier
+    from repro.kernels import ops
     from repro.models.model import build_model
     from repro.train.loop import jit_train_step
     from repro.train.train_state import init_state
 
-    # the tier decides by the default backend, which is the CPU here
+    # the tier and the attention decide by the default backend, which is
+    # the CPU here: steer both to what they take on one chip
     monkeypatch.setattr(HostTier, "places_host_memory",
                         staticmethod(lambda: True))
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
     B, S = 2, 16
     tc = TrainConfig(total_steps=4, warmup_steps=0)
     model = build_model(RunConfig(
@@ -148,3 +179,11 @@ def test_host_tier_train_step_scopes(one_chip, monkeypatch):
     assert all("layers" in re.split(r"[/()]", op) for op in named.values()), \
         named
     assert "/tier.recompute/" in text
+    # the attention runs as Pallas kernels in the forward, the recompute
+    # and the backward, each call inside attention_core
+    kernels = [m.group(1) for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+    assert kernels and all("attention_core" in op for op in kernels), kernels
+    assert any("tier.recompute" in op for op in kernels), kernels
+    assert any("tier.recompute" not in op for op in kernels), kernels

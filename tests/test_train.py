@@ -299,3 +299,34 @@ def test_tier_counter_counts_executed_steps(runs, grad_accum):
         assert rep[d]["per_step"] == per
         assert rep[d]["wire_bytes"] == rep[d]["raw_bytes"] == runs * per
         assert rep[d]["calls"] == runs * CFG.num_layers * grad_accum
+
+
+# ---------------------------------------------------------------------------
+# which attention the training step takes
+def _loss_grads_paths(B=2, S=16):
+    from repro.kernels import ops
+    model, _, _, batch = _host_step(B, S)
+    params = model.init(jax.random.PRNGKey(0))
+    before = ops.attention_paths()
+    (loss, _), grads = jax.jit(jax.value_and_grad(model.loss_fn,
+                                                  has_aux=True))(params,
+                                                                 batch)
+    after = ops.attention_paths()
+    return loss, grads, {k: after[k] - before[k] for k in after}
+
+
+def test_train_attention_path_follows_the_backend(monkeypatch):
+    """On the CPU every traced training attention call takes the XLA
+    blockwise twin.  With the backend check reading TPU (the kernels run
+    interpreted) every one takes the Pallas pair instead, the host tier's
+    recompute included, and loss and gradients equal the twin's."""
+    from repro.kernels import ops
+    loss_x, grads_x, paths_x = _loss_grads_paths()
+    assert paths_x["pallas"] == 0 and paths_x["xla"] > 0
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    loss_p, grads_p, paths_p = _loss_grads_paths()
+    assert paths_p == {"pallas": paths_x["xla"], "xla": 0}
+    np.testing.assert_allclose(float(loss_p), float(loss_x), rtol=1e-3)
+    for a, b in zip(jax.tree.leaves(grads_p), jax.tree.leaves(grads_x)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.max(np.abs(a - b)) <= 2e-2 * max(np.abs(b).max(), 1e-6)
