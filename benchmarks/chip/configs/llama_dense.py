@@ -14,8 +14,9 @@ Matmuls run at ``precision="highest"`` (a TPU would otherwise run float32
 matmuls in bfloat16).  ``precision="fp8"`` is the control: every matmul
 operand rounded to float8 e4m3 with a per-tensor scale, the step below
 the configuration's bfloat16 that would tempt a later change.  In
-training the rounding is straight-through, so the backward pass sees the
-rounded forward operands.
+training the backward's matmuls are fp8 too, as in fp8 training: they
+take the rounded forward operands (straight-through) and the incoming
+gradient rounded to float8 e5m2 under a per-tensor scale.
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
+E5M2_MAX = 57344.0
 Q_BLOCK = 1024
 PAD = 512
 
@@ -87,20 +89,36 @@ def _init(items, key):
 
 
 # ---------------------------------------------------------------------------
+def _quant(x, dtype, top):
+    """``x`` rounded to the 8-bit float ``dtype`` under a per-tensor scale
+    that maps its largest magnitude to ``top``."""
+    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / top
+    return (x / s).astype(dtype).astype(jnp.float32) * s
+
+
 def _fp8(x):
     """Round to float8 e4m3 under a per-tensor scale, straight-through."""
-    s = jax.lax.stop_gradient(jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
-                              / F8_MAX)
-    q = (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+    q = _quant(jax.lax.stop_gradient(x), jnp.float8_e4m3fn, F8_MAX)
     return x + jax.lax.stop_gradient(q - x)
+
+
+@jax.custom_vjp
+def _fp8_cotangent(y):
+    """The identity; its backward rounds the incoming gradient to float8
+    e5m2 under a per-tensor scale, as an fp8 matmul's backward takes it."""
+    return y
+
+
+_fp8_cotangent.defvjp(lambda y: (y, None),
+                      lambda _, g: (_quant(g, jnp.float8_e5m2, E5M2_MAX),))
 
 
 def matmul(precision: str):
     if precision == "f32":
         return partial(jnp.einsum, precision=HIGHEST)
     if precision == "fp8":
-        return lambda eq, a, b: jnp.einsum(eq, _fp8(a), _fp8(b),
-                                           precision=HIGHEST)
+        return lambda eq, a, b: _fp8_cotangent(
+            jnp.einsum(eq, _fp8(a), _fp8(b), precision=HIGHEST))
     raise ValueError(f"unknown precision {precision!r}")
 
 

@@ -1,35 +1,91 @@
-"""What every runner shares: the program's configuration built from the
-cell's file, device facts, compile counting and the traced window."""
+"""What every runner shares: the program's configuration and mesh built
+from the cell's files, device facts, compile counting and the traced
+window."""
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 import shutil
 import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
+from harness import spec
 from harness import trace as tr
 
 
+def _published(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields that the published keys of ``cfg`` state,
+    each from the key that means the same thing."""
+    out: Dict[str, Any] = {"name": cfg["name"]}
+    for field, key, kind in (
+            ("num_layers", "num_hidden_layers", int),
+            ("d_model", "hidden_size", int),
+            ("num_heads", "num_attention_heads", int),
+            ("num_kv_heads", "num_key_value_heads", int),
+            ("d_ff", "intermediate_size", int),
+            ("vocab_size", "vocab_size", int),
+            ("rope_theta", "rope_theta", float),
+            ("tie_embeddings", "tie_word_embeddings", bool)):
+        if key in cfg:
+            out[field] = kind(cfg[key])
+    if cfg.get("head_dim"):
+        out["head_dim"] = int(cfg["head_dim"])
+    if cfg.get("sliding_window"):
+        out["attention"] = "swa"
+        out["window"] = int(cfg["sliding_window"])
+    if "torch_dtype" in cfg:
+        out["dtype"] = cfg["torch_dtype"]
+    return out
+
+
 def model_config(cfg: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file."""
+    """The program's ModelConfig for a configuration file: a dense
+    decoder (RMSNorm, rotary attention, SwiGLU) from the published keys,
+    with the fields of the file's ``program`` object over it.  A
+    ``program`` field that is not a ModelConfig field, or that differs
+    from the published key meaning the same thing, is refused: the file
+    states the value that is run (a change goes under ``changed``)."""
     from repro.configs.base import ModelConfig
-    if cfg.get("hidden_act", "silu") != "silu":
-        raise ValueError("only SwiGLU (silu) MLPs are described here")
-    window = cfg.get("sliding_window") or 0
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or 0, d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"],
-        attention="swa" if window else "full", window=window or 4096,
-        rope_theta=float(cfg["rope_theta"]), act="silu", norm="rmsnorm",
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg.get("torch_dtype", "bfloat16"))
+    program = dict(cfg.get("program", {}))
+    fields = {f.name: f for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(program) - set(fields))
+    if unknown:
+        raise spec.SpecError(f"{cfg['name']}: program keys {unknown} are "
+                             f"not fields of the program's ModelConfig")
+    published = _published(cfg)
+    clash = {k: (published[k], v) for k, v in program.items()
+             if k in published and v != published[k]}
+    if clash:
+        raise spec.SpecError(f"{cfg['name']}: program values contradict "
+                             f"the published keys (published, program): "
+                             f"{clash}")
+    if "act" not in program and cfg.get("hidden_act", "silu") != "silu":
+        raise spec.SpecError(f"{cfg['name']}: hidden_act "
+                             f"{cfg['hidden_act']!r} needs the program's "
+                             f"act in the file's program object")
+    args = dict(family="dense", head_dim=0, attention="full", window=4096,
+                act="silu", norm="rmsnorm", dtype="bfloat16")
+    args.update(published)
+    args.update({k: tuple(v) if isinstance(v, list) else v
+                 for k, v in program.items()})
+    missing = sorted(k for k, f in fields.items() if k not in args
+                     and f.default is dataclasses.MISSING)
+    if missing:
+        raise spec.SpecError(f"{cfg['name']}: neither the published keys "
+                             f"nor the program object state {missing}")
+    return ModelConfig(**args)
+
+
+def mesh(cell: Dict[str, Any], devices):
+    """The program's mesh over the cell's devices and its MeshPlan: on
+    one chip no mesh, as the program runs unsharded there."""
+    from repro.configs.base import MeshPlan
+    shape, axes = spec.mesh(cell)
+    if len(devices) == 1:
+        return None, MeshPlan(shape, axes)
+    from repro.launch.mesh import make_mesh
+    return make_mesh(shape, axes, devices=devices), MeshPlan(shape, axes)
 
 
 def memory_peak_bytes(devices) -> int:

@@ -1,9 +1,13 @@
 """Operations and bytes the algorithm needs, from shapes alone.
 
 The model is a dense decoder (RMSNorm, rotary GQA attention with an
-optional sliding window, SwiGLU MLP).  "Needed" counts each matmul once,
-as 2 x multiply-adds, and attention over the (query, visible key) pairs:
-no recomputation, no padding, no work for slots that carry no session.
+optional sliding window, SwiGLU MLP), every layer alike, as the
+published keys of a configuration file state it; a file with a
+``program`` object brings its own counts, under these names and
+signatures, in its reference module (``spec.count``).  "Needed" counts
+each matmul once, as 2 x multiply-adds, and attention over the (query,
+visible key) pairs: no recomputation, no padding, no work for slots that
+carry no session.
 """
 from __future__ import annotations
 
@@ -65,6 +69,11 @@ def decode_flops(cfg: Dict[str, Any], length: int) -> float:
     W = _dims(cfg)[7]
     rows = length + 1 if W <= 0 else min(length + 1, W)
     return forward_flops(cfg, 1, rows, 1)
+
+
+def attention_layers(cfg: Dict[str, Any]) -> int:
+    """Layers whose attention reads the KV cache: every layer."""
+    return _dims(cfg)[5]
 
 
 def paged_decode_need(cfg: Dict[str, Any], length: int, n_active: int,

@@ -83,17 +83,29 @@ def scopes_of(op_name: str) -> List[str]:
             for seg in op_name.split("/")]
 
 
+def forward_scopes_of(op_name: str) -> List[str]:
+    """The scope names on an ``op_name`` path that no ``transpose`` wraps.
+    The backward that JAX derives from a scope's forward carries
+    ``transpose(<scope>)``, so this leaves it out:
+    ``.../body/transpose(tier.recompute)/jvp(attention_core)/...`` ->
+    ``[..., "body", "attention_core", ...]``."""
+    return [name for seg, name in zip(op_name.split("/"),
+                                      scopes_of(op_name))
+            if "transpose(" not in seg]
+
+
 def scope_seconds(trace: Dict[str, object], lo: float, hi: float,
-                  match: Callable[[List[str]], bool]) -> float:
+                  match: Callable[[List[str]], bool],
+                  names: Callable[[str], List[str]] = scopes_of) -> float:
     """Device self seconds of the operations that start in [lo, hi) and
-    whose scope names (``scopes_of`` their op_name) ``match`` accepts,
+    whose scope names (``names`` of their op_name) ``match`` accepts,
     divided by the number of devices."""
     devs = trace["devices"]
     tot = 0.0
     for d in devs.values():
         scope = d.get("scope", {})
         for name, s, _, self_ns, _ in d["ops"]:
-            if lo <= s < hi and match(scopes_of(scope.get(name, ""))):
+            if lo <= s < hi and match(names(scope.get(name, ""))):
                 tot += self_ns
     return tot / max(len(devs), 1) / 1e9
 

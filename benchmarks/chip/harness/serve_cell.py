@@ -15,6 +15,9 @@ request that never gets one failed.  Then, with the program's state
 freed, the plain reference re-reads a seeded sample of the served
 sessions (the longest among them) and ``correct`` compares the widest gap
 by which a served token's logit lies below the reference's best.
+
+On several chips the model is built on the mix's mesh (``common.mesh``)
+and its weights are made in their sharded layout.
 """
 from __future__ import annotations
 
@@ -26,14 +29,17 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from harness import common, flops, spec, traffic
+from harness import common, spec, traffic
 
 GRACE_S = 60.0
 NOTHING = 1e30      # stands for a number that could not be read (JSON has
                     # no infinity): it fails every limit and every bound
 
 
-def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int):
+def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int, mesh=None,
+          plan=None):
+    """The model, its weights made on the device from the seed (sharded
+    over ``mesh`` where given) and the engine."""
     import jax
     from repro.configs.base import (MemoryPlan, MeshPlan, RunConfig,
                                     ShapeConfig, TrainConfig)
@@ -45,10 +51,12 @@ def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int):
     run = RunConfig(model=common.model_config(cfg),
                     shape=ShapeConfig("serve", es["max_len"], es["slots"],
                                       "decode"),
-                    mesh=MeshPlan((1,), ("data",)),
+                    mesh=plan or MeshPlan((1,), ("data",)),
                     memory=MemoryPlan(policy="none"), train=TrainConfig())
-    model = build_model(run)
-    params = jax.jit(model.init)(jax.random.PRNGKey(traffic.key_seed(seed)))
+    model = build_model(run, mesh=mesh)
+    init = jax.jit(model.init) if mesh is None else \
+        jax.jit(model.init, out_shardings=model.param_shardings())
+    params = init(jax.random.PRNGKey(traffic.key_seed(seed)))
     sched = {"quantum": es["quantum"]} if "quantum" in es else {}
     eng = Engine(model, params, batch=es["slots"], max_len=es["max_len"],
                  scheduler=build_scheduler(es["scheduler"], **sched),
@@ -64,6 +72,8 @@ class Driver:
 
     def __init__(self, eng, cfg: Dict[str, Any], page: int):
         self.eng, self.cfg, self.page = eng, cfg, page
+        self.prefill_flops = spec.count(cfg, "prefill_flops")
+        self.decode_flops = spec.count(cfg, "decode_flops")
         self.stamps: Dict[int, List[float]] = collections.defaultdict(list)
         self.sessions: Dict[int, Any] = {}
         self.steps = 0
@@ -80,9 +90,9 @@ class Driver:
     def on_token(self, sess, tok) -> None:
         self.stamps[sess.uid].append(time.perf_counter())
         if len(sess.tokens) == 1:
-            self.flops += flops.prefill_flops(self.cfg, sess.length)
+            self.flops += self.prefill_flops(self.cfg, sess.length)
         else:
-            self.flops += flops.decode_flops(self.cfg, sess.length)
+            self.flops += self.decode_flops(self.cfg, sess.length)
 
     def submit(self, req) -> None:
         from repro.serve.engine import Request
@@ -130,19 +140,25 @@ def _instrument(eng) -> None:
             setattr(obj, name, wrapped)
 
 
-def warm(drv: Driver, mix: Dict[str, Any], vocab: int, seed: int) -> None:
-    """Prefill and decode once at every prompt length the mix uses."""
+def warm(drv: Driver, mix: Dict[str, Any], vocab: int, seed: int,
+         rounds: int = 1) -> None:
+    """Prefill and decode once at every prompt length the mix uses, in
+    each of ``rounds`` rounds.  On a mesh it takes two: the engine's page
+    pool starts unsharded and its programs return it sharded, so only the
+    second round runs every program on the layout that the window sees."""
     from harness.traffic import Request
     p = mix["prompt"]
-    sizes = p.get("buckets") or p.get("values") or [p.get("value")]
+    sizes = sorted(set(int(s) for s in (
+        p.get("buckets") or p.get("values") or [p.get("value")])))
     rng = traffic.rng_for(seed, 6)
-    for i, n in enumerate(sorted(set(int(s) for s in sizes))):
-        drv.submit(Request(uid=10 ** 9 + i, due_s=0.0,
-                           prompt=rng.integers(0, vocab, size=n,
-                                               dtype=np.int32),
-                           max_new_tokens=2))
-    while drv.busy():
-        drv.step()
+    for r in range(rounds):
+        for i, n in enumerate(sizes):
+            drv.submit(Request(uid=10 ** 9 + r * len(sizes) + i, due_s=0.0,
+                               prompt=rng.integers(0, vocab, size=n,
+                                                   dtype=np.int32),
+                               max_new_tokens=2))
+        while drv.busy():
+            drv.step()
 
 
 def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
@@ -153,12 +169,15 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     cfg, mix = cell["config_spec"], cell["traffic_spec"]
     es = mix["engine"]
     vocab = cfg["vocab_size"]
+    need = spec.count(cfg, "paged_decode_need")
+    layers = spec.count(cfg, "attention_layers")(cfg)
+    mesh, plan = common.mesh(cell, devices)
     counter = common.CompileCounter()
     t0 = common.now()
-    model, params, eng = build(cfg, mix, seed)
+    model, params, eng = build(cfg, mix, seed, mesh, plan)
     drv = Driver(eng, cfg, es["page_size"])
     t_built = common.now()
-    warm(drv, mix, vocab, seed)
+    warm(drv, mix, vocab, seed, 1 if mesh is None else 2)
     t_warm = common.now()
     reqs = traffic.serve_requests(mix, seed, seconds, vocab)
     pending = collections.deque(reqs)
@@ -293,14 +312,14 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         # that a stretch between two rotations cannot miss the spill
         a, b = snap["a"], snap["b"]
         w0, w1 = snap["start"], snap["end"]
-        page, L = es["page_size"], cfg["num_hidden_layers"]
+        page = es["page_size"]
         res["traced"] = prof.result
         res["counters"] = {
             "kind": "serve", "chips": len(devices), "page_size": page,
             "model_flops": b["flops"] - a["flops"],
-            "paged_need": [flops.paged_decode_need(cfg, ln, n, page)
+            "paged_need": [need(cfg, ln, n, page)
                            for ln, n in drv.calls[a["calls"]:b["calls"]]],
-            "layers": L, "decode_kernel": bool(es["decode_kernel"]),
+            "layers": layers, "decode_kernel": bool(es["decode_kernel"]),
             "engine_steps": w1["steps"] - w0["steps"],
             "decode_calls": w1["decode_calls"] - w0["decode_calls"],
             "tokens": w1["tokens"] - w0["tokens"],

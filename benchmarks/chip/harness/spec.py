@@ -2,11 +2,28 @@
 
 Everything that belongs to one configuration, traffic mix, per-layer
 metric or cell lives in a file of its own, so that a later change adds a
-cell by adding files:
+configuration, a mix or a cell by adding files and entries, and edits
+none of the harness:
 
-* ``configs/<config>.json``  -- the sizes, as run; ``reference`` names the
-  plain reference module beside it (``configs/<reference>.py``);
-* ``traffic/<traffic>.json`` -- the mix's parameters (``traffic.py`` reads it);
+* ``configs/<config>.json``  -- the sizes, as run, under the published
+  keys.  An optional ``program`` object holds the fields of the program's
+  ``ModelConfig`` that the published keys do not say (``family``, ``act``,
+  ``num_experts``, ``top_k``, ``ssm_state``, ``hybrid_attn_every``, ...),
+  by their field names: a hybrid, SSM or MoE model is described there
+  (``common.model_config``).  ``reference`` names the plain reference
+  module beside it (``configs/<reference>.py``);
+* the reference module -- ``train_readings`` and ``serve_gaps``, what
+  ``correct`` compares against, and optionally the configuration's own
+  counts of operations and bytes: ``train_step_flops``,
+  ``prefill_flops``, ``decode_flops``, ``paged_decode_need`` and
+  ``attention_layers``, with the signatures of ``harness/flops.py``.
+  That counts the dense SwiGLU decoder of the published keys and stands
+  in for any count the module leaves out, but only in a file with no
+  ``program`` object, whose module must define every count (``count``);
+* ``traffic/<traffic>.json`` -- the mix's parameters (``traffic.py`` reads
+  it); ``mesh`` (``{"shape": [...], "axes": [...]}``) lays the cell's
+  chips out as the program's mesh, ``[chips]`` over ``data`` where absent
+  (``mesh``);
 * ``metrics/<metric>.py``    -- a reader with ``read(m) -> float | None``;
 * ``limits/<workload>.json`` -- the limits of the numbers ``correct`` compares.
 """
@@ -14,8 +31,9 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
-from typing import Any, Callable, Dict, List
+from typing import Any, Callable, Dict, List, Tuple
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -70,6 +88,48 @@ def reference(cfg: Dict[str, Any], base: str = HERE):
                        f"chipbench_ref_{name}")
 
 
+# the counts a reference module may define in place of harness/flops.py's
+COUNTS = ("train_step_flops", "prefill_flops", "decode_flops",
+          "paged_decode_need", "attention_layers")
+
+
+def count(cfg: Dict[str, Any], name: str, base: str = HERE) -> Callable:
+    """The configuration's count ``name``: its reference module's where
+    that defines one, else ``harness/flops.py``'s.  That counts the dense
+    SwiGLU decoder of the published keys alone, so a file with a
+    ``program`` object, which may change what is built, brings its own."""
+    if name not in COUNTS:
+        raise SpecError(f"unknown count {name!r}; known: {COUNTS}")
+    own = getattr(reference(cfg, base), name, None)
+    if own is not None:
+        return own
+    if "program" in cfg:
+        raise SpecError(f"configuration {cfg['name']!r} has a program "
+                        f"object: its reference module must define {name} "
+                        f"(harness/flops.py counts the dense SwiGLU decoder "
+                        f"of the published keys)")
+    from harness import flops
+    return getattr(flops, name)
+
+
+def mesh(cell: Dict[str, Any]) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:
+    """The shape and axis names the cell lays over its chips: the mix's
+    ``mesh`` where it gives one, else all chips on ``data``."""
+    chips = int(cell["chips"])
+    m = cell["traffic_spec"].get("mesh")
+    if m is None:
+        return (chips,), ("data",)
+    shape, axes = tuple(int(n) for n in m["shape"]), tuple(m["axes"])
+    if len(shape) != len(axes) or len(set(axes)) != len(axes):
+        raise SpecError(f"mesh {m} needs one distinct axis name per "
+                        f"dimension")
+    size = math.prod(shape)
+    if size != chips:
+        raise SpecError(f"mesh {m} holds {size} chips, the cell "
+                        f"{cell.get('name')!r} asks for {chips}")
+    return shape, axes
+
+
 def metric_reader(name: str, base: str = HERE) -> Callable:
     path = os.path.join(base, "metrics", f"{name}.py")
     if not os.path.exists(path):
@@ -89,6 +149,7 @@ def cell(workload: str, bench: Dict[str, Any]) -> Dict[str, Any]:
     out = dict(w)
     out["config_spec"] = config(w["config"])
     out["traffic_spec"] = traffic(w["traffic"])
+    mesh(out)
     return out
 
 

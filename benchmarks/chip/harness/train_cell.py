@@ -9,6 +9,13 @@ compares: each step's loss, the first clipped gradient's norm per leaf
 last of them.  The window then keeps driving the same step object until
 the deadline.  After it, with the program's state freed, the plain
 reference repeats those steps from the seed and the readings are compared.
+
+On several chips the model is built on the mix's mesh (``common.mesh``),
+its state made in its sharded layout and each batch split over the data
+axis, as the training launcher lays them out.  A traced run also maps the
+compiled step's operations to the program's named scopes
+(``harness/scopes.py``) and counts the memory tier's executed bytes over
+the traced steps.
 """
 from __future__ import annotations
 
@@ -20,16 +27,22 @@ from typing import Any, Dict
 
 import numpy as np
 
-from harness import common, flops, spec, traffic
+from harness import common, scopes, spec, traffic
 
 
-def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int):
+def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int, mesh=None,
+          plan=None):
+    """The model, its optimizer settings, the jitted step, the state made
+    on the device from the seed, and the sharding of a batch (None
+    without a mesh).  ``mesh``/``plan`` come from ``common.mesh``; none
+    and a plan of one chip on ``data`` by default."""
     import jax
+    from jax.sharding import NamedSharding
     from repro.configs.base import (MemoryPlan, MeshPlan, RunConfig,
                                     ShapeConfig, TrainConfig)
     from repro.models.model import build_model
     from repro.train import loop
-    from repro.train.train_state import init_state
+    from repro.train.train_state import init_state, state_shardings
 
     hp = mix["optimizer"]
     tc = TrainConfig(learning_rate=hp["learning_rate"],
@@ -39,22 +52,34 @@ def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int):
                      beta2=hp["beta2"], eps=hp["eps"],
                      grad_clip=hp["grad_clip"])
     mcfg = common.model_config(cfg)
-    run = RunConfig(model=mcfg, shape=ShapeConfig("train", mix["seq"],
-                                                  mix["batch"], "train"),
-                    mesh=MeshPlan((1,), ("data",)),
+    shape = ShapeConfig("train", mix["seq"], mix["batch"], "train")
+    run = RunConfig(model=mcfg, shape=shape,
+                    mesh=plan or MeshPlan((1,), ("data",)),
                     memory=MemoryPlan(policy=mix["policy"]), train=tc)
-    model = build_model(run)
-    step = loop.jit_train_step(model, tc)
+    model = build_model(run, mesh=mesh)
     key = jax.random.PRNGKey(traffic.key_seed(seed))
-    state = jax.jit(lambda k: init_state(model, tc, k))(key)
-    return model, tc, step, state
+    if mesh is None:
+        step = loop.jit_train_step(model, tc)
+        state = jax.jit(lambda k: init_state(model, tc, k))(key)
+        return model, tc, step, state, None
+    # the launcher's layout: the state sharded as the step expects it,
+    # each batch split over the data axis (``Model.batch_specs``)
+    batch_sharding = {k: NamedSharding(mesh, p)
+                      for k, p in model.batch_specs(shape).items()}
+    step = loop.jit_train_step(model, tc, batch_sharding)
+    state = jax.jit(lambda k: init_state(model, tc, k),
+                    out_shardings=state_shardings(model, tc))(key)
+    return model, tc, step, state, batch_sharding
 
 
 class Feed:
-    """The window's feed: batch t of the mix, moved to the device."""
+    """The window's feed: batch t of the mix, moved to the device (split
+    over the mesh's data axis where ``sharding`` is given)."""
 
-    def __init__(self, seed: int, mix: Dict[str, Any], vocab: int):
+    def __init__(self, seed: int, mix: Dict[str, Any], vocab: int,
+                 sharding=None):
         self.seed, self.mix, self.vocab = seed, mix, vocab
+        self.sharding = sharding
         self.t = 0
 
     def host_batch(self, t: int) -> Dict[str, np.ndarray]:
@@ -62,10 +87,16 @@ class Feed:
                                    self.mix["seq"], self.vocab)
 
     def __next__(self):
-        import jax
-        b = jax.device_put(self.host_batch(self.t))
+        b = self.place(self.host_batch(self.t))
         self.t += 1
         return b
+
+    def place(self, batch: Dict[str, np.ndarray]):
+        import jax
+        if self.sharding is None:
+            return jax.device_put(batch)
+        return {k: jax.device_put(v, self.sharding[k])
+                for k, v in batch.items()}
 
 
 def _leaf_norms_fn():
@@ -97,11 +128,13 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     cfg, mix = cell["config_spec"], cell["traffic_spec"]
     hp = mix["optimizer"]
     n_check = int(mix.get("check_steps", 3))
+    step_flops = spec.count(cfg, "train_step_flops")
+    mesh, plan = common.mesh(cell, devices)
     counter = common.CompileCounter()
 
     t0 = common.now()
-    model, tc, step, state = build(cfg, mix, seed)
-    feed = Feed(seed, mix, cfg["vocab_size"])
+    model, tc, step, state, sharding = build(cfg, mix, seed, mesh, plan)
+    feed = Feed(seed, mix, cfg["vocab_size"], sharding)
     norms, diff_norms = _leaf_norms_fn()
     p0 = jax.jit(lambda p: jax.tree.map(jnp.copy, p))(state["params"])
     prog: Dict[str, Any] = {"losses": []}
@@ -117,17 +150,27 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     jax.block_until_ready(state)
     setup_s = common.now() - t0
 
+    op_names: Dict[str, str] = {}
+    if trace:
+        # the compiled step's instruction -> op_name map, which names the
+        # scope of each operation in the trace (a v5e profile's events
+        # carry none); compiled again here, or read from the cache
+        op_names = scopes.hlo_op_names(step.lower(
+            state, feed.place(feed.host_batch(feed.t))).compile().as_text())
+
     # -------------------------------------------------------------- window
     tokens_per_step = mix["batch"] * mix["seq"]
     prof = common.Profiler() if trace else None
     counter.on = True
     steps = traced = 0
+    traced_wire = 0.0
     start = common.now()
     deadline = start + seconds
     end = start
     while end < deadline:
         tracing = prof is not None and steps == 1
         ctx = prof.window() if tracing else contextlib.nullcontext()
+        wire0 = _tier_wire(model, "wire_bytes") if tracing else 0.0
         with ctx:
             n = int(mix.get("traced_steps", 3)) if tracing else 1
             for _ in range(n):
@@ -139,12 +182,15 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
                     jax.block_until_ready(m)
                 steps += 1
                 traced += tracing
+        if tracing:
+            traced_wire = _tier_wire(model, "wire_bytes") - wire0
         end = common.now()
     counter.on = False
     window_s = end - start
     peak = common.memory_peak_bytes(devices)
     tier = model.runtime.traffic_report() if model.runtime.moves_bytes \
         else {}
+    tier_per_step = _tier_wire(model, "per_step")
     del state, m, batch
     gc.collect()
 
@@ -183,17 +229,27 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     }
     if prof is not None:
         tr = prof.result
+        scopes.attach(tr.trace, op_names)
         res["traced"] = tr
+        res["log"]["scope_split"] = scopes.scope_split(tr.trace, tr.lo, tr.hi)
         res["counters"] = {
             "kind": "train", "chips": len(devices),
-            "model_flops": traced * flops.train_step_flops(
-                cfg, mix["batch"], mix["seq"]),
+            "model_flops": traced * step_flops(cfg, mix["batch"],
+                                               mix["seq"]),
             "steps": traced, "policy": mix["policy"],
-            "tier_bytes_per_step": sum(
-                v.get("wire_bytes", 0.0) for k, v in tier.items()
-                if isinstance(v, dict) and k in ("stash", "fetch"))
-            * cfg["num_hidden_layers"]}
+            "tokens": traced * tokens_per_step,
+            "tier_wire_bytes": traced_wire,
+            "tier_bytes_per_step": tier_per_step}
     return res
+
+
+def _tier_wire(model, key: str) -> float:
+    """The memory tier's stash + fetch wire bytes: executed so far
+    (``wire_bytes``) or one step's (``per_step``), 0 where it moves none."""
+    if not model.runtime.moves_bytes:
+        return 0.0
+    rep = model.runtime.traffic_report()
+    return sum(rep.get(d, {}).get(key, 0.0) for d in ("stash", "fetch"))
 
 
 def compare(prog: Dict[str, Any], ref: Dict[str, Any],

@@ -158,3 +158,29 @@ def test_wire_bytes_reader():
     assert got == 69120
     assert read(Measurement({"kind": "train", "steps": 3}, traced, {})) \
         is None
+
+
+def test_recompute_share_leaves_out_the_backward_of_the_recompute():
+    """The re-run forward counts; the backward that JAX derives from it
+    (the attention's backward kernels among it, named
+    ``transpose(tier.recompute)``) does not, though it counts as
+    attention."""
+    body = "jit(train_step)/transpose(jvp(layers))/while/body/closed_call/"
+    fwd = body + "tier.recompute/jvp(attention_core)/jit(flash_fwd)/pallas"
+    bwd = body + "transpose(tier.recompute)/jvp(attention_core)/" \
+        "jit(flash_bwd)/pallas"
+    assert "tier.recompute" in sc.forward_scopes_of(fwd)
+    assert sc.forward_scopes_of(bwd) == [
+        "train_step", "while", "body", "closed_call", "attention_core",
+        "flash_bwd", "pallas"]
+    trace = {"devices": {"0": {"ops": [["fwd.1", 0.0, 2e8, 2e8, 0],
+                                       ["bwd.2", 2e8, 3e8, 3e8, 0],
+                                       ["copy.3", 5e8, 1e8, 1e8, 0]],
+                               "xfer": []}}, "host": []}
+    sc.attach(trace, {"fwd.1": fwd, "bwd.2": bwd})
+    traced = Traced(trace, 0.0, 1e9)
+    m = Measurement({"kind": "train"}, traced, {})
+    assert spec.metric_reader("step.recompute_share")(m) == \
+        pytest.approx(100 * 0.2 / m.window_s)
+    assert spec.metric_reader("step.attention_core_share")(m) == \
+        pytest.approx(100 * 0.5 / m.window_s)
